@@ -2,9 +2,11 @@
 and the continuous-batching engine with per-request SamplingParams and
 streaming completions (greedy + sampled lanes in one batch).
 
-    PYTHONPATH=src python examples/serve_lm.py --arch recurrentgemma-2b
+    PYTHONPATH=src python examples/serve_lm.py --arch recurrentgemma-2b \
+        --reduced
 (recurrent archs demonstrate O(1)-state decode; attention archs the KV
-cache path — both reduced configs on CPU.)
+cache path. Published widths by default, like launch/serve.py; --reduced
+serves the small same-family variant sized for CPU runs.)
 """
 import argparse
 import time
@@ -12,7 +14,6 @@ import time
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs import get_config
 from repro.launch.mesh import make_host_mesh
 from repro.launch.serve import generate
@@ -25,9 +26,12 @@ def main():
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--gen", type=int, default=24)
+    ap.add_argument("--reduced", action="store_true")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     if cfg.frontend == "audio":
         prompts = jax.random.randint(jax.random.PRNGKey(1),
@@ -36,7 +40,7 @@ def main():
     else:
         prompts = jax.random.randint(jax.random.PRNGKey(1),
                                      (args.batch, 8), 0, cfg.vocab_size)
-    with compat.set_mesh(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         t0 = time.time()
         toks = generate(params, cfg, prompts, args.gen, temperature=0.8)
         dt = time.time() - t0

@@ -67,7 +67,7 @@ case "${1:-fast}" in
            # Perfetto trace + metrics dump (serve.py exits nonzero on
            # schema errors; re-validated here from the files on disk)
            obs_dir="$(mktemp -d)"
-           python -m repro.launch.serve --requests 4 --slots 2 \
+           python -m repro.launch.serve --reduced --requests 4 --slots 2 \
                 --prompt-len 8 16 --max-new 2 4 --seed 0 \
                 --trace-out "$obs_dir/trace.json" \
                 --metrics-out "$obs_dir/metrics.json"
@@ -107,7 +107,7 @@ PY
                 --threshold 0.5
            # quantized + tiered KV smoke: serve.py runs int8 pools with
            # a host spill tier end-to-end, then the correctness gates
-           python -m repro.launch.serve --requests 6 --slots 2 \
+           python -m repro.launch.serve --reduced --requests 6 --slots 2 \
                 --prompt-len 12 24 --max-new 2 4 --seed 0 \
                 --kv-dtype int8 --host-cache-blocks 16
            python - <<'PY'
@@ -175,7 +175,7 @@ PY
            # make the burst pressure sustain past the policy windows
            # even at tiny decode lengths)
            as_dir="$(mktemp -d)"
-           python -m repro.launch.serve --workload bursty --requests 20 \
+           python -m repro.launch.serve --reduced --workload bursty --requests 20 \
                 --slots 2 --prompt-len 8 16 --max-new 2 4 \
                 --burst-rate 400 --base-rate 2 --burst-every 30 \
                 --burst-len 0.04 --autoscale --min-replicas 1 \
@@ -241,7 +241,7 @@ PY
            # the shed counter + burn-rate gauges + quantile sketches,
            # and the anomaly dump must be a schema-valid Perfetto trace
            slo_dir="$(mktemp -d)"
-           python -m repro.launch.serve --workload bursty --requests 20 \
+           python -m repro.launch.serve --reduced --workload bursty --requests 20 \
                 --slots 2 --prompt-len 8 16 --max-new 2 4 \
                 --burst-rate 400 --base-rate 2 --burst-every 30 \
                 --burst-len 0.04 --seed 0 \

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 
 def _kernel(a_ref, x_ref, h0_ref, y_ref, hout_ref, h_ref, *, tc):
     ci = pl.program_id(2)
@@ -42,7 +40,7 @@ def _kernel(a_ref, x_ref, h0_ref, y_ref, hout_ref, h_ref, *, tc):
 
 @functools.partial(jax.jit, static_argnames=("tc", "cb", "interpret"))
 def rglru_scan(a, x, h0=None, *, tc: int = 128, cb: int = 256,
-               interpret: bool = True):
+               interpret: bool):
     """a, x: (B, T, C); h0: (B, C) or None. Returns (h_seq, h_final)."""
     B, T, C = a.shape
     if h0 is None:
@@ -63,7 +61,7 @@ def rglru_scan(a, x, h0=None, *, tc: int = 128, cb: int = 256,
                    jax.ShapeDtypeStruct((B, C), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((cb,), jnp.float32)],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, x, h0)
     return y, h_fin

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref, *,
             tc):
@@ -52,7 +50,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("tc", "interpret"))
-def rwkv6_scan(r, k, v, w, u, *, tc: int = 64, interpret: bool = True):
+def rwkv6_scan(r, k, v, w, u, *, tc: int = 64, interpret: bool):
     """r/k/v/w: (B, H, T, N); u: (H, N). Returns (y, final_state)."""
     B, H, T, N = r.shape
     tc = min(tc, T)
@@ -71,7 +69,7 @@ def rwkv6_scan(r, k, v, w, u, *, tc: int = 64, interpret: bool = True):
                    jax.ShapeDtypeStruct((B, H, N, N), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(r, k, v, w, u)
     return y, s_fin

@@ -33,7 +33,7 @@ def _kernel(x_ref, gx_ref, gz_ref, mu_ref, w_ref, eta_ref, gamma_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
 def svrg_update(x, g_x, g_z, mu, w_anchor, eta, gamma, *,
-                interpret: bool = True, block_rows: int = BLOCK_ROWS):
+                interpret: bool, block_rows: int = BLOCK_ROWS):
     """All array args are 1-D of equal length; eta/gamma scalars."""
     (n,) = x.shape
     pad = (-n) % LANES

@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
-# The two lines above MUST run before any jax import (device count locks on
-# first init). Everything below is ordinary code.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import (device count and backend
+# lock on first init): the dry-run is a CPU tool and never takes a chip,
+# whatever the machine has. Everything below is ordinary code.
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh) cell.
 
@@ -27,7 +29,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import SHAPES, get_config, list_archs
 from repro.distributed import sharding as shd
 from repro.launch import (cost_model, hlo_analysis, inputs as inputs_lib,
@@ -207,7 +208,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, variant: str,
         # production aliasing; otherwise memory doubles
         donate = {"train": (0, 1), "decode": (1,),
                   "prefill": ()}[shape.kind]
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
             t_lower = time.time() - t0
             compiled = lowered.compile()

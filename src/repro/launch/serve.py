@@ -5,54 +5,60 @@ throughput/latency telemetry.
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
         --requests 32 --slots 8 --prompt-len 24 64 --max-new 8 32 --rate 50
 
+The model is served at its published widths; `--reduced` (like
+`launch/train.py`'s) swaps in the small same-family variant, which is
+what the CPU examples below use.
+
     # shared-prefix traffic (system prompt + per-request suffix) with
     # prefix caching and explicit prefill length buckets:
-    PYTHONPATH=src python -m repro.launch.serve --workload shared-prefix \
-        --prefix-len 48 --prefix-cache --prefill-buckets 16 32 64
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
+        --workload shared-prefix --prefix-len 48 --prefix-cache \
+        --prefill-buckets 16 32 64
 
     # n-gram speculative decoding (greedy lanes stay bit-identical to
     # generate()) on a repetitive-text workload:
-    PYTHONPATH=src python -m repro.launch.serve --workload repetitive \
-        --speculate 4 --draft ngram --max-new 16 32
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
+        --workload repetitive --speculate 4 --max-new 16 32
 
     # per-request sampling (position-keyed: batch-composition
     # independent) with nucleus/top-k warping and a stop sequence —
     # composes with speculation (distribution-preserving accept/reject):
-    PYTHONPATH=src python -m repro.launch.serve --temperature 0.8 \
+    PYTHONPATH=src python -m repro.launch.serve --reduced --temperature 0.8 \
         --top-k 50 --top-p 0.95 --stop 7 11 --speculate 4
 
     # multi-replica cluster: a router fronting N full engine stacks
     # (per-replica device pools + prefix caches) with least-loaded or
     # prefix-affinity placement; outputs are bit-identical to a
     # single-replica run (batch-composition independence, one level up):
-    PYTHONPATH=src python -m repro.launch.serve --replicas 2 \
+    PYTHONPATH=src python -m repro.launch.serve --reduced --replicas 2 \
         --router prefix --workload multi-tenant --tenants 4
 
     # elastic autoscaling on bursty traffic: start one replica, scale
     # out (jit-warm standby stacks) under sustained queue pressure and
     # drain back when the burst passes; outputs stay bit-identical to
     # a fixed-size run. Mix priority classes to exercise preemption:
-    PYTHONPATH=src python -m repro.launch.serve --workload bursty \
+    PYTHONPATH=src python -m repro.launch.serve --reduced --workload bursty \
         --autoscale --min-replicas 1 --max-replicas 3 --priorities 0 1
 
     # observability: export a Perfetto-loadable trace (request lifecycle
     # spans per slot + the dispatch timeline) and a metrics dump
     # (counters/gauges/histograms + occupancy time series); outputs stay
     # bit-identical with the recorder on:
-    PYTHONPATH=src python -m repro.launch.serve --requests 8 \
+    PYTHONPATH=src python -m repro.launch.serve --reduced --requests 8 \
         --trace-out trace.json --metrics-out metrics.json
 
     # SLO layer: declare a TTFT objective (streaming latency sketches +
     # burn-rate windows), shed hopeless requests against a per-request
     # deadline, scale on burn rate instead of queue depth, and arm the
     # anomaly flight recorder on diurnal (sinusoidal-rate) traffic:
-    PYTHONPATH=src python -m repro.launch.serve --workload diurnal \
+    PYTHONPATH=src python -m repro.launch.serve --reduced --workload diurnal \
         --slo-ttft-ms 100 --slo-shed --deadline-ms 500 \
         --autoscale --slo-autoscale --flight-recorder flight.json
 
     # legacy single-batch path (token-by-token cache priming; kept as the
     # benchmark baseline and for the audio/vision frontends):
-    PYTHONPATH=src python -m repro.launch.serve --mode naive --batch 4
+    PYTHONPATH=src python -m repro.launch.serve --reduced --mode naive \
+        --batch 4
 
 `generate()` below is the seed serving path, unchanged: it primes the KV
 cache one token at a time and decodes a fixed batch in lockstep. The
@@ -69,10 +75,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import get_config
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.autoscaler import Autoscaler, AutoscalePolicy
 from repro.serving.engine import (Request, ServingEngine, bursty_requests,
                                   diurnal_requests,
@@ -211,7 +217,7 @@ def _engine_kwargs(args, max_seq_len):
                 prefill_buckets=args.prefill_buckets,
                 prefill_max_batch=args.prefill_batch,
                 prefill_chunk=args.prefill_chunk,
-                speculate=args.speculate, draft=args.draft,
+                speculate=args.speculate,
                 ngram=args.ngram, kv_dtype=args.kv_dtype,
                 host_cache_blocks=args.host_cache_blocks,
                 priority_aging=args.priority_aging,
@@ -343,6 +349,9 @@ def _run_naive(args, cfg, params):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the small same-family variant (CPU runs) "
+                         "instead of the published widths")
     ap.add_argument("--mode", default="engine", choices=["engine", "naive"])
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--slots", type=int, default=8)
@@ -424,8 +433,6 @@ def main():
     ap.add_argument("--speculate", type=int, default=0,
                     help="max draft tokens per verify dispatch "
                          "(speculative decoding; 0 = off, greedy-only)")
-    ap.add_argument("--draft", default="ngram", choices=["ngram"],
-                    help="draft proposer (ngram = prompt lookup)")
     ap.add_argument("--ngram", type=int, default=3,
                     help="longest n-gram the proposer matches")
     ap.add_argument("--kv-dtype", default="fp16",
@@ -513,10 +520,13 @@ def main():
     if args.slo_autoscale and not args.autoscale:
         raise SystemExit("--slo-autoscale requires --autoscale")
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    enable_compile_cache()
     mesh = make_host_mesh()
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if args.mode == "engine":
             _run_engine(args, cfg, params)
         else:

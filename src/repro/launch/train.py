@@ -3,6 +3,8 @@
     PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
         --steps 200 --optimizer mbprox --ckpt-dir /tmp/run1 [--resume]
 
+Trains the architecture at its published widths; `--reduced` (like
+`launch/serve.py`'s) swaps in the small same-family variant for CPU runs.
 Runs on whatever devices exist (host mesh); the same step builders power
 the 512-chip dry-run. Checkpoint/restart via runtime.fault_tolerance.
 """
@@ -14,7 +16,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.checkpoint import checkpoint as ckpt_lib
 from repro.configs import get_config
 from repro.data.synthetic import TokenStream
@@ -22,6 +23,7 @@ from repro.launch import steps as steps_lib
 from repro.launch.mesh import data_axes, make_host_mesh
 from repro.models import lm
 from repro.optim.optimizers import Schedule, adamw
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def make_batch(cfg, stream, key, batch_size, n_micro):
@@ -34,7 +36,7 @@ def make_batch(cfg, stream, key, batch_size, n_micro):
 def train(arch: str, steps: int, *, optimizer: str = "mbprox",
           batch_size: int = 8, n_micro: int = 2, seq_len: int = 64,
           lr: float = 3e-3, ckpt_dir: str | None = None,
-          resume: bool = False, reduced: bool = True, log_every: int = 10,
+          resume: bool = False, reduced: bool = False, log_every: int = 10,
           seed: int = 0):
     cfg = get_config(arch)
     if reduced:
@@ -65,7 +67,7 @@ def train(arch: str, steps: int, *, optimizer: str = "mbprox",
 
     losses = []
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start, steps):
             key = jax.random.fold_in(jax.random.PRNGKey(seed + 1), step)
             batch = make_batch(cfg, stream, key, batch_size, n_micro)
@@ -96,12 +98,15 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true")
-    ap.add_argument("--full-config", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="train the small same-family variant (CPU runs) "
+                         "instead of the published widths")
     args = ap.parse_args()
+    enable_compile_cache()
     _, losses = train(args.arch, args.steps, optimizer=args.optimizer,
                       batch_size=args.batch_size, seq_len=args.seq_len,
                       lr=args.lr, ckpt_dir=args.ckpt_dir,
-                      resume=args.resume, reduced=not args.full_config)
+                      resume=args.resume, reduced=args.reduced)
     print(f"final loss: {losses[-1]:.4f} "
           f"(start {losses[0]:.4f}, min {min(losses):.4f})")
 

@@ -43,7 +43,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.optim.optimizers import Optimizer, sgd
 
 
@@ -146,7 +145,7 @@ def make_mbprox_step(loss_fn: Callable, mp_cfg: MBProxConfig, mesh,
     def step(params, inner_state, batch, lr):
         batch_spec = jax.tree.map(lambda _: P(None, dp_axes), batch)
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             local_subproblem,
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params),

@@ -56,12 +56,7 @@ def pool_dtype(cfg: ModelConfig, kv_dtype: str = "fp16") -> jnp.dtype:
     if kv_dtype == "int8":
         return jnp.dtype(jnp.int8)
     if kv_dtype == "fp8":
-        fp8 = getattr(jnp, "float8_e4m3fn", None)
-        if fp8 is None:
-            raise ValueError(
-                "kv_dtype='fp8' needs jax.numpy.float8_e4m3fn, which this "
-                "jax build does not provide; use 'int8' or 'fp16'")
-        return jnp.dtype(fp8)
+        return jnp.dtype(jnp.float8_e4m3fn)
     raise ValueError(f"unknown kv_dtype {kv_dtype!r}; expected {KV_DTYPES}")
 
 

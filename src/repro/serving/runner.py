@@ -42,9 +42,11 @@ position-keyed per request (fold_in(PRNGKey(seed), pos)); the runner
 holds no sampler state at all, which is what makes a request's stream
 independent of batch composition.
 
-All jitted state is donated, so pools update in place. The bucket-grid
-helpers live in `serving/bucketing.py` (shared with the bench's shape
-assertions).
+All jitted state is donated, so pools update in place. The weights are
+an argument (not donated) of every program that reads them, never a
+closed-over constant, so each compiled variant shares the one device
+copy. The bucket-grid helpers live in `serving/bucketing.py` (shared
+with the bench's shape assertions).
 """
 from __future__ import annotations
 
@@ -194,9 +196,10 @@ class ModelRunner:
         self.reset_stats()
 
         K = self.max_logprobs
+        self.params = params
 
-        def _decode(state, tokens, positions, tables, temps, topks, topps,
-                    seeds, do_sample, want_alt):
+        def _decode(params, state, tokens, positions, tables, temps, topks,
+                    topps, seeds, do_sample, want_alt):
             logits, state = lm.decode_step_paged(params, cfg, state, tokens,
                                                  positions, tables)
             if do_sample:
@@ -207,11 +210,11 @@ class ModelRunner:
             alt = sampling.top_alternatives(logits, K) if want_alt else None
             return tok, lp, alt, state
 
-        self._decode_fn = jax.jit(_decode, donate_argnums=(0,),
-                                  static_argnums=(8, 9))
+        self._decode_fn = jax.jit(_decode, donate_argnums=(1,),
+                                  static_argnums=(9, 10))
 
-        def _verify(state, tokens, positions, counts, tables, temps, topks,
-                    topps, seeds, do_sample, want_alt):
+        def _verify(params, state, tokens, positions, counts, tables, temps,
+                    topks, topps, seeds, do_sample, want_alt):
             logits, state, snaps = lm.decode_verify_paged(
                 params, cfg, state, tokens, positions, counts, tables)
             if do_sample:
@@ -224,20 +227,21 @@ class ModelRunner:
             alt = sampling.top_alternatives(logits, K) if want_alt else None
             return emit, accept, lp, alt, state, snaps
 
-        self._verify_fn = jax.jit(_verify, donate_argnums=(0,),
-                                  static_argnums=(9, 10))
+        self._verify_fn = jax.jit(_verify, donate_argnums=(1,),
+                                  static_argnums=(10, 11))
 
         def _commit(state, snaps, idx):
             return lm.commit_decode_state(cfg, state, snaps, idx)
 
         self._commit_fn = jax.jit(_commit, donate_argnums=(0,))
 
-        def _prefill(state, toks, lengths, cached, rows, slots, resume):
+        def _prefill(params, state, toks, lengths, cached, rows, slots,
+                     resume):
             return lm.prefill_paged(params, cfg, state, toks, lengths,
                                     cached, rows, slots, resume=resume)
 
-        self._prefill_fn = jax.jit(_prefill, donate_argnums=(0,),
-                                   static_argnums=(6,))
+        self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,),
+                                   static_argnums=(7,))
 
         def _first(last, positions, temps, topks, topps, seeds, do_sample,
                    want_alt):
@@ -431,9 +435,9 @@ class ModelRunner:
                                             score_bytes)
 
         last, self.state = self._prefill_fn(
-            self.state, jnp.asarray(toks), jnp.asarray(lengths),
-            jnp.asarray(cached), jnp.asarray(tables), jnp.asarray(slots),
-            resume)
+            self.params, self.state, jnp.asarray(toks),
+            jnp.asarray(lengths), jnp.asarray(cached), jnp.asarray(tables),
+            jnp.asarray(slots), resume)
         do_sample = bool(temps.max() > 0.0)
         want_alt = any(r.sampling.logprobs for r in rows)
         first, lp, alt = self._first_fn(
@@ -481,9 +485,9 @@ class ModelRunner:
         want_alt = self.any_alt
         temps, topks, topps, seeds = self._sampling_device()
         next_tok, lp, alt, self.state = self._decode_fn(
-            self.state, jnp.asarray(tokens), jnp.asarray(positions),
-            self._tables_device(), temps, topks, topps, seeds, do_sample,
-            want_alt)
+            self.params, self.state, jnp.asarray(tokens),
+            jnp.asarray(positions), self._tables_device(), temps, topks,
+            topps, seeds, do_sample, want_alt)
         out = np.asarray(next_tok), np.asarray(lp), self._host_alt(alt)
         if obs.enabled:
             variant = (do_sample, want_alt)
@@ -521,9 +525,10 @@ class ModelRunner:
         want_alt = self.any_alt
         temps, topks, topps, seeds = self._sampling_device()
         emit, accept, lp, alt, self.state, self._snaps = self._verify_fn(
-            self.state, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(counts), self._tables_device(), temps, topks,
-            topps, seeds, do_sample, want_alt)
+            self.params, self.state, jnp.asarray(tokens),
+            jnp.asarray(positions), jnp.asarray(counts),
+            self._tables_device(), temps, topks, topps, seeds, do_sample,
+            want_alt)
         out = (np.asarray(emit), np.asarray(accept), np.asarray(lp),
                self._host_alt(alt))
         if obs.enabled:

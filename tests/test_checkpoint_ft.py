@@ -132,8 +132,8 @@ def test_train_driver_resume(tmp_path):
     d = str(tmp_path / "run")
     _, losses1 = train("smollm-135m", 4, optimizer="baseline",
                        batch_size=4, n_micro=2, seq_len=16, ckpt_dir=d,
-                       log_every=100)
+                       reduced=True, log_every=100)
     _, losses2 = train("smollm-135m", 6, optimizer="baseline",
                        batch_size=4, n_micro=2, seq_len=16, ckpt_dir=d,
-                       resume=True, log_every=100)
+                       resume=True, reduced=True, log_every=100)
     assert len(losses2) == 2        # resumed at step 4, ran 4..5

@@ -26,7 +26,7 @@ def _rand(i, shape, dtype=jnp.float32, scale=1.0):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_svrg_update_sweep(n, dtype):
     args = [_rand(i, (n,), dtype) for i in range(5)]
-    out = svrg_update(*args, 0.1, 0.5)
+    out = svrg_update(*args, 0.1, 0.5, interpret=True)
     expect = ref.svrg_update_ref(*args, jnp.asarray(0.1, dtype),
                                  jnp.asarray(0.5, dtype))
     tol = 1e-6 if dtype == jnp.float32 else 2e-2
@@ -40,7 +40,8 @@ def test_svrg_update_block_sizes():
     args = [_rand(i, (5000,)) for i in range(5)]
     expect = ref.svrg_update_ref(*args, 0.05, 2.0)
     for br in [16, 128, 1024]:
-        out = svrg_update(*args, 0.05, 2.0, block_rows=br)
+        out = svrg_update(*args, 0.05, 2.0, block_rows=br,
+                          interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                    atol=1e-6)
 
@@ -59,7 +60,7 @@ def test_flash_attention_sweep(B, H, KV, S, hd, dtype):
     q = _rand(1, (B, H, S, hd), dtype)
     k = _rand(2, (B, KV, S, hd), dtype)
     v = _rand(3, (B, KV, S, hd), dtype)
-    out = flash_attention(q, k, v, bq=64, bk=64)
+    out = flash_attention(q, k, v, bq=64, bk=64, interpret=True)
     expect = ref.flash_attention_ref(q, k, v)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -73,7 +74,8 @@ def test_flash_attention_noncausal_and_blocks():
     v = _rand(3, (1, 2, 192, 64))
     expect = ref.flash_attention_ref(q, k, v, causal=False)
     for bq, bk in [(64, 64), (192, 96), (96, 192)]:
-        out = flash_attention(q, k, v, causal=False, bq=bq, bk=bk)
+        out = flash_attention(q, k, v, causal=False, bq=bq, bk=bk,
+                              interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                    atol=2e-5, rtol=2e-5)
 
@@ -88,7 +90,8 @@ def test_flash_attention_matches_model_path():
     model_out = chunked_causal_attention(q, k, v, chunk=32)
     kern_out = flash_attention(q.transpose(0, 2, 1, 3),
                                k.transpose(0, 2, 1, 3),
-                               v.transpose(0, 2, 1, 3), bq=32, bk=32)
+                               v.transpose(0, 2, 1, 3), bq=32, bk=32,
+                               interpret=True)
     np.testing.assert_allclose(np.asarray(model_out),
                                np.asarray(kern_out.transpose(0, 2, 1, 3)),
                                atol=2e-4, rtol=2e-4)
@@ -106,7 +109,7 @@ def test_rwkv6_scan_sweep(B, H, T, N, dtype):
     v = _rand(3, (B, H, T, N), dtype, 0.5)
     w = jax.nn.sigmoid(_rand(4, (B, H, T, N)) * 2).astype(dtype)
     u = _rand(5, (H, N), jnp.float32, 0.1)
-    y, s = rwkv6_scan(r, k, v, w, u, tc=32)
+    y, s = rwkv6_scan(r, k, v, w, u, tc=32, interpret=True)
     y_ref, s_ref = ref.rwkv6_ref(r, k, v, w, u)
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -121,7 +124,8 @@ def test_rwkv6_chunk_invariance():
     r, k, v = (_rand(i, (B, H, T, N), scale=0.5) for i in range(3))
     w = jax.nn.sigmoid(_rand(7, (B, H, T, N)))
     u = _rand(8, (H, N), scale=0.1)
-    outs = [rwkv6_scan(r, k, v, w, u, tc=tc)[0] for tc in (16, 32, 96)]
+    outs = [rwkv6_scan(r, k, v, w, u, tc=tc, interpret=True)[0]
+            for tc in (16, 32, 96)]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                    atol=1e-4, rtol=1e-4)
@@ -136,7 +140,7 @@ def test_rwkv6_chunk_invariance():
 def test_rglru_scan_sweep(B, T, C, dtype):
     a = jax.nn.sigmoid(_rand(1, (B, T, C)) * 2).astype(dtype)
     x = _rand(2, (B, T, C), dtype, 0.3)
-    y, h = rglru_scan(a, x, tc=32, cb=min(C, 128))
+    y, h = rglru_scan(a, x, tc=32, cb=min(C, 128), interpret=True)
     y_ref, h_ref = ref.rglru_ref(a, x)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -153,7 +157,7 @@ def test_rglru_initial_state_and_chunks():
     h0 = _rand(3, (B, C))
     y_ref, h_ref = ref.rglru_ref(a, x, h0)
     for tc in (8, 64):
-        y, h = rglru_scan(a, x, h0, tc=tc, cb=32)
+        y, h = rglru_scan(a, x, h0, tc=tc, cb=32, interpret=True)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    atol=1e-5, rtol=1e-5)
         np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
@@ -178,7 +182,8 @@ def test_rwkv6_consistency_with_model_layer():
     y_kern, _ = rwkv6_scan(r.transpose(perm), k.transpose(perm),
                            v.transpose(perm),
                            w.astype(jnp.float32).transpose(perm),
-                           params["bonus_u"].astype(jnp.float32), tc=8)
+                           params["bonus_u"].astype(jnp.float32), tc=8,
+                           interpret=True)
     y_kern = y_kern.transpose(0, 2, 1, 3).reshape(B, S, -1)
     y_kern = recurrent._rwkv_group_norm(y_kern, params["ln_scale"],
                                         cfg.n_heads, cfg.head_dim) * g

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.optim import compression as comp
 from repro.optim.optimizers import (Schedule, adamw, clip_by_global_norm,
                                     sgd)
@@ -87,7 +86,7 @@ def test_mbprox_step_solves_prox_subproblem():
     step = make_mbprox_step(loss_fn, mp, mesh, ("data",))
     params = {"w": jnp.zeros(16)}
     batch = {"scale": jnp.ones((4, 1))}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         new_p, _, m = jax.jit(step)(params, (), batch, jnp.float32(0.05))
     # prox point: argmin loss + gamma/2 ||w||^2 = (H + gamma I)^{-1} b
     loss, _ = _quad_problem()
@@ -118,7 +117,7 @@ def test_mbprox_sync_equals_local_on_one_shard():
         mp = MBProxConfig(gamma=0.2, inner_momentum=0.9, inner_passes=2,
                           dane_correction=False, variant=variant)
         step = make_mbprox_step(loss_fn, mp, mesh, ("data",))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p, s, _ = jax.jit(step)(params,
                                     jax.tree.map(jnp.zeros_like, params),
                                     batch, jnp.float32(0.03))

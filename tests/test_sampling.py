@@ -457,17 +457,24 @@ def test_top_alternatives_unit():
 def test_topk_alternative_logprobs_decode_and_verify_paths():
     """SamplingParams.logprobs=k (satellite): Completion carries the k
     alternative (ids, logprobs) per emitted position, through the plain
-    decode path AND the speculative verify path (repetitive prompt so
-    chains really verify), for greedy and sampled lanes — and the
-    greedy realization is unchanged by asking for them."""
+    decode path AND the speculative verify path (oracle drafts of the
+    greedy continuation, so chains verify and are accepted by
+    construction), for greedy and sampled lanes — and the greedy
+    realization is unchanged by asking for them."""
     cfg = get_config("smollm-135m").reduced()
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(5)
     pat = rng.integers(0, cfg.vocab_size, 5).astype(np.int32)
     prompt = np.tile(pat, 4)[:16]
+    greedy = _expect(params, cfg, Request(rid=0, prompt=prompt,
+                                          max_new_tokens=8))
+    oracle = _OracleProposer([([int(t) for t in prompt],
+                               [int(t) for t in greedy])])
     for speculate in (0, 3):
         eng = ServingEngine(params, cfg, num_slots=2, block_size=4,
                             max_seq_len=32, speculate=speculate)
+        if speculate:
+            eng.scheduler._proposers = [oracle] * eng.num_slots
         reqs = [Request(rid=0, prompt=prompt.copy(),
                         sampling=SamplingParams(max_new_tokens=8,
                                                 logprobs=3)),
@@ -487,8 +494,7 @@ def test_topk_alternative_logprobs_decode_and_verify_paths():
         np.testing.assert_allclose(g.logprobs, g.top_logprobs[:, 0],
                                    rtol=1e-5)
         assert (np.diff(g.top_logprobs, axis=1) <= 1e-6).all()
-        np.testing.assert_array_equal(
-            g.tokens, _expect(params, cfg, reqs[0]))   # output unchanged
+        np.testing.assert_array_equal(g.tokens, greedy)  # output unchanged
         s = done[1]
         assert s.top_ids.shape == (8, 2)
         # a sampled token need not be the argmax, but its RAW-dist
@@ -497,6 +503,7 @@ def test_topk_alternative_logprobs_decode_and_verify_paths():
     # streaming carries the same alternatives the completion records
     eng = ServingEngine(params, cfg, num_slots=2, block_size=4,
                         max_seq_len=32, speculate=3)
+    eng.scheduler._proposers = [oracle] * eng.num_slots
     got_ids, final = [], None
     for ev in eng.stream([Request(rid=0, prompt=prompt.copy(),
                                   sampling=SamplingParams(

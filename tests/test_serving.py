@@ -49,7 +49,7 @@ def test_paged_attention_kernel_sweep(B, H, KV, hd, bs, M, dtype):
     bt = (1 + jnp.arange(B * M, dtype=jnp.int32)).reshape(B, M)
     cl = jnp.asarray([(i * 7 + 3) % (M * bs) + 1 for i in range(B)],
                      jnp.int32)
-    out = paged_attention(q, kp, vp, bt, cl)
+    out = paged_attention(q, kp, vp, bt, cl, interpret=True)
     expect = ref.paged_attention_ref(q, kp, vp, bt, cl)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -64,7 +64,7 @@ def test_paged_attention_kernel_empty_slot():
     vp = _rand(3, (5, 8, 2, 32))
     bt = jnp.array([[1, 2], [0, 0]], jnp.int32)
     cl = jnp.array([9, 0], jnp.int32)
-    out = np.asarray(paged_attention(q, kp, vp, bt, cl))
+    out = np.asarray(paged_attention(q, kp, vp, bt, cl, interpret=True))
     assert np.isfinite(out).all()
     np.testing.assert_array_equal(out[1], 0.0)
     expect = np.asarray(ref.paged_attention_ref(q, kp, vp, bt, cl))
@@ -183,6 +183,54 @@ def test_engine_matches_generate_exactly():
     assert len(done) == B
     for c in done:
         np.testing.assert_array_equal(c.tokens, expect[c.rid])
+
+
+# ----------------------------------------------------------------------------
+# runner programs read the weights as arguments, never as constants
+# ----------------------------------------------------------------------------
+
+@pytest.mark.parametrize("program", ["decode", "verify", "prefill"])
+def test_runner_programs_take_weights_as_arguments(program):
+    """A closed-over weight array would be lowered into every compiled
+    variant as a `stablehlo.constant` (one copy of the model per
+    executable). Each program that reads the weights must instead list
+    every weight leaf among its arguments and hold no weight-shaped
+    constant."""
+    import re
+
+    from repro.serving.runner import ModelRunner
+
+    cfg = get_config("smollm-135m").reduced()
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    slots, mb = 2, 4
+    runner = ModelRunner(params, cfg, num_slots=slots, block_size=4,
+                         num_blocks=1 + slots * mb, max_blocks_per_seq=mb,
+                         speculate=2)
+    z = jnp.zeros(slots, jnp.int32)
+    tables = jnp.zeros((slots, mb), jnp.int32)
+    sampling_args = runner._sampling_device()
+    if program == "decode":
+        lowered = runner._decode_fn.lower(
+            runner.params, runner.state, z, z, tables, *sampling_args,
+            False, True)
+    elif program == "verify":
+        lowered = runner._verify_fn.lower(
+            runner.params, runner.state, jnp.zeros((slots, 3), jnp.int32),
+            z, z, tables, *sampling_args, False, True)
+    else:
+        lowered = runner._prefill_fn.lower(
+            runner.params, runner.state, jnp.zeros((slots, 16), jnp.int32),
+            z, z, tables, z, False)
+    weight_args = jax.tree.leaves(lowered.args_info[0][0])
+    assert len(weight_args) == len(jax.tree.leaves(params))
+    assert not any(a.donated for a in weight_args)
+    weight_types = {"x".join(map(str, w.shape)) + "x"
+                    + str(jnp.dtype(w.dtype)).replace("float", "f")
+                    for w in jax.tree.leaves(params)}
+    consts = re.findall(r"stablehlo\.constant dense<[^>]*> : tensor<([^>]*)>",
+                        lowered.as_text())
+    assert consts                        # the pattern does match constants
+    assert not weight_types & set(consts)
 
 
 @pytest.mark.parametrize("arch", ["smollm-135m", "recurrentgemma-2b"])

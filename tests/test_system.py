@@ -12,19 +12,20 @@ import pytest
 def test_train_reduces_loss_mbprox():
     from repro.launch.train import train
     _, losses = train("smollm-135m", 60, optimizer="mbprox", lr=5e-2,
-                      batch_size=8, seq_len=32, log_every=1000)
+                      batch_size=8, seq_len=32, reduced=True,
+                      log_every=1000)
     assert min(losses) < losses[0] - 0.2, (losses[0], min(losses))
 
 
 def test_train_reduces_loss_baseline():
     from repro.launch.train import train
     _, losses = train("smollm-135m", 60, optimizer="baseline", lr=2e-2,
-                      batch_size=8, seq_len=32, log_every=1000)
+                      batch_size=8, seq_len=32, reduced=True,
+                      log_every=1000)
     assert min(losses) < losses[0] - 0.3
 
 
 def test_generate_end_to_end():
-    from repro import compat
     from repro.configs import get_config
     from repro.launch.mesh import make_host_mesh
     from repro.launch.serve import generate
@@ -33,14 +34,40 @@ def test_generate_end_to_end():
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     prompts = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
                                  cfg.vocab_size)
-    with compat.set_mesh(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         toks = generate(params, cfg, prompts, 12)
     assert toks.shape == (2, 12)
     assert bool((toks >= 0).all()) and bool((toks < cfg.vocab_size).all())
     # greedy decode is deterministic
-    with compat.set_mesh(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         toks2 = generate(params, cfg, prompts, 12)
     np.testing.assert_array_equal(np.asarray(toks), np.asarray(toks2))
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and nothing
+    overrides it; otherwise the cache is one fixed, git-ignored
+    directory at the repo root."""
+    import pathlib
+
+    from repro.runtime import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = compile_cache.enable_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    if from_env:
+        assert path == str(tmp_path) and now == was
+    else:
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert path == now == str(root / ".jax_cache")
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
 
 
 def test_hlo_parser_known_flops():
