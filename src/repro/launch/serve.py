@@ -47,6 +47,12 @@ what the CPU examples below use.
     PYTHONPATH=src python -m repro.launch.serve --reduced --requests 8 \
         --trace-out trace.json --metrics-out metrics.json
 
+    # the stack's own host spans (serve.step, serve.scheduler.*,
+    # serve.runner.* with their stage/fetch parts, serve.host.gc) on
+    # the device's clock: a jax.profiler trace of the served run
+    PYTHONPATH=src python -m repro.launch.serve --reduced --requests 8 \
+        --profile-dir /tmp/serve-profile
+
     # SLO layer: declare a TTFT objective (streaming latency sketches +
     # burn-rate windows), shed hopeless requests against a per-request
     # deadline, scale on burn rate instead of queue depth, and arm the
@@ -67,6 +73,7 @@ engine replaces it for sustained traffic — see repro.serving.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import time
@@ -515,6 +522,12 @@ def main():
                     help="write the metrics-registry dump JSON (counters/"
                          "gauges/histograms + SchedulerStats time series). "
                          "Enables the observability recorder.")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="run the served traffic under jax.profiler.trace("
+                         "DIR): the device's programs and the stack's "
+                         "serve.* host spans on one clock (open the "
+                         ".xplane.pb in TensorBoard's profile plugin or "
+                         "XProf)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     if args.slo_autoscale and not args.autoscale:
@@ -526,7 +539,9 @@ def main():
     enable_compile_cache()
     mesh = make_host_mesh()
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
-    with jax.set_mesh(mesh):
+    profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+               else contextlib.nullcontext())
+    with jax.set_mesh(mesh), profile:
         if args.mode == "engine":
             _run_engine(args, cfg, params)
         else:

@@ -63,7 +63,9 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.serving.block_manager import BlockAllocator
 from repro.serving.kv_cache import ATTN_KINDS
-from repro.serving.observability import NULL_OBS, Observability
+from repro.serving.observability import (NULL_OBS, Observability,
+                                         install_gc_span, spanned,
+                                         step_span)
 from repro.serving.runner import ModelRunner
 from repro.serving.sampling import SamplingParams
 from repro.serving.scheduler import (Completion, Request, Scheduler,
@@ -185,6 +187,7 @@ class ServingEngine:
         self.kv_dtype = kv_dtype
         self.host_cache_blocks = max(0, int(host_cache_blocks))
         self.obs = obs or NULL_OBS
+        install_gc_span()
         if slo_tracker is not None:
             self.slo = slo_tracker
         elif slo_policy is not None:
@@ -235,6 +238,7 @@ class ServingEngine:
     def has_work(self) -> bool:
         return self.scheduler.has_work
 
+    @spanned("serve.engine.stats")
     def stats(self) -> SchedulerStats:
         """Structured occupancy snapshot (queue depth, slot occupancy,
         allocator free/cached block counts) — what a replica router
@@ -290,6 +294,10 @@ class ServingEngine:
         advances by exactly one prefill chunk per iteration, BEFORE the
         decode/verify dispatch, so running lanes keep emitting tokens
         throughout a long admission instead of stalling behind it."""
+        with step_span(self.steps):
+            self._step()
+
+    def _step(self) -> None:
         self.scheduler.admit()
         self.scheduler.prefill_step()
         if self.obs.enabled:

@@ -43,6 +43,15 @@ Four pieces:
     last `capacity` events leading up to it without having paid for
     full tracing all along.
 
+Besides the recorder, the stack opens profiler spans (`span`,
+`step_span`, `spanned`; every name is in `SPANS`) where its work
+happens: the engine step, each scheduler phase, each runner dispatch
+with its `stage` (host arrays and uploads) and `fetch` (blocking
+read-back) children, and every garbage collection. They are always on,
+whatever the recorder, and record nothing unless `jax.profiler` is
+tracing; then they land in the profiler's own trace, on the device
+trace's clock (under a microsecond each otherwise).
+
 The default recorder is `NULL_OBS`: every method is a no-op and
 `enabled` is False, so layers guard their bookkeeping behind one
 attribute check and the off path costs nothing. Recording never
@@ -53,9 +62,14 @@ tests/test_observability.py).
 from __future__ import annotations
 
 import copy
+import functools
+import gc
 import json
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import (StepTraceAnnotation, TraceAnnotation,
+                          annotate_function)
 
 # trace_event thread id of the per-replica dispatch track (slot tracks
 # use tid == slot index; any real slot count stays far below this)
@@ -69,6 +83,72 @@ FLIGHT_TID = 95
 METRICS_SCHEMA = "repro.serving.metrics/v2"
 METRICS_SCHEMAS = ("repro.serving.metrics/v1", METRICS_SCHEMA)
 TRACE_SCHEMA = "repro.serving.trace_event/v1"
+
+
+# ----------------------------------------------------------------------------
+# profiler spans
+# ----------------------------------------------------------------------------
+
+# every host span the serving stack opens; the chip benchmark's trace
+# reduction reads them by these names
+SPANS = (
+    "serve.step",
+    "serve.scheduler.admit", "serve.scheduler.prefill_step",
+    "serve.scheduler.prepare_decode", "serve.scheduler.consume",
+    "serve.scheduler.prepare_verify", "serve.scheduler.consume_verify",
+    "serve.runner.decode", "serve.runner.decode.stage",
+    "serve.runner.decode.fetch",
+    "serve.runner.prefill", "serve.runner.prefill.stage",
+    "serve.runner.prefill.fetch",
+    "serve.runner.verify", "serve.runner.verify.stage",
+    "serve.runner.verify.fetch",
+    "serve.runner.copy_block",
+    "serve.engine.stats",
+    "serve.host.gc",
+)
+
+
+def span(name: str) -> TraceAnnotation:
+    """A host span named `name` in the profiler's own trace."""
+    return TraceAnnotation(name)
+
+
+def step_span(n: int) -> StepTraceAnnotation:
+    """The `serve.step` span of engine iteration `n`."""
+    return StepTraceAnnotation("serve.step", step_num=n)
+
+
+def spanned(name: str):
+    """Decorator: each call of the function runs inside `span(name)`."""
+    if name not in SPANS:
+        raise ValueError(f"span {name!r} is not in SPANS")
+    return functools.partial(annotate_function, name=name)
+
+
+class _GCSpan:
+    """A `gc.callbacks` hook: one `serve.host.gc` span from each
+    collection's start to its stop."""
+
+    def __init__(self):
+        self._open: Optional[TraceAnnotation] = None
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._open = span("serve.host.gc")
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+_GC_SPAN = _GCSpan()
+
+
+def install_gc_span() -> None:
+    """Puts the `serve.host.gc` hook in `gc.callbacks`, once per
+    process."""
+    if _GC_SPAN not in gc.callbacks:
+        gc.callbacks.append(_GC_SPAN)
 
 
 # ----------------------------------------------------------------------------

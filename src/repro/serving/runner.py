@@ -64,7 +64,8 @@ from repro.serving.block_manager import NULL_BLOCK
 from repro.serving.bucketing import (chain_buckets, next_pow2,  # noqa: F401
                                      normalize_buckets, pick_bucket,
                                      pow2_buckets, width_buckets)
-from repro.serving.observability import NULL_OBS, Observability
+from repro.serving.observability import (NULL_OBS, Observability, span,
+                                         spanned)
 from repro.serving.sampling import GREEDY, SamplingParams
 
 RECURRENT_KINDS = ("rwkv", "rec")
@@ -373,6 +374,7 @@ class ModelRunner:
         """Smallest verify bucket covering an n-token draft chain."""
         return pick_bucket(n, self.verify_buckets)
 
+    @spanned("serve.runner.prefill")
     def prefill(self, rows: List[PrefillRow], *, resume: bool = False,
                 chunk: Optional[Tuple[int, int]] = None):
         """Run one bucketed batched prefill and sample each row's first
@@ -396,56 +398,58 @@ class ModelRunner:
         n = len(rows)
         obs = self._obs
         t0 = self._now() if obs.enabled else 0.0
-        ls = self.suffix_bucket(max(r.suffix_len for r in rows))
-        width = pick_bucket(n, self.width_buckets)
-        toks = np.zeros((width, ls), np.int32)
-        lengths = np.zeros(width, np.int32)
-        cached = np.zeros(width, np.int32)
-        tables = np.full((width, self.max_blocks_per_seq), NULL_BLOCK,
-                         np.int32)
-        slots = np.full(width, self.num_slots, np.int32)   # pad rows drop
-        temps = np.zeros(width, np.float32)
-        topks = np.zeros(width, np.int32)
-        topps = np.ones(width, np.float32)
-        seeds = np.zeros(width, np.int32)
-        for i, r in enumerate(rows):
-            suf = r.tokens[r.start:]
-            toks[i, :len(suf)] = suf
-            lengths[i] = len(r.tokens)
-            cached[i] = r.cached_len
-            tables[i] = r.table_row
-            slots[i] = r.slot
-            temps[i] = r.sampling.temperature
-            topks[i] = r.sampling.top_k
-            topps[i] = r.sampling.top_p
-            seeds[i] = sampling.seed32(r.sampling.seed)
-        self.prefill_shapes.add((width, ls))
-        self.prefill_dispatches += 1
-        self.prefill_padded_tokens += width * ls
-        self.prefill_computed_tokens += sum(r.suffix_len for r in rows)
-        # analytic peak attention-score bytes for this dispatch: the
-        # streamed path (attention.streamed_paged_attention) bounds the
-        # pool band at attn_chunk keys, plus the (ls, ls) suffix tile —
-        # f32 scores per head. Benchmarks assert this stays flat as the
-        # prompt grows past the chunk budget.
-        kv_band = min(self.max_blocks_per_seq * self.block_size,
-                      self.cfg.attn_chunk)
-        score_bytes = 4 * width * self.cfg.n_heads * ls * (kv_band + ls)
-        self.prefill_peak_score_bytes = max(self.prefill_peak_score_bytes,
-                                            score_bytes)
-
-        last, self.state = self._prefill_fn(
-            self.params, self.state, jnp.asarray(toks),
-            jnp.asarray(lengths), jnp.asarray(cached), jnp.asarray(tables),
-            jnp.asarray(slots), resume)
-        do_sample = bool(temps.max() > 0.0)
-        want_alt = any(r.sampling.logprobs for r in rows)
-        first, lp, alt = self._first_fn(
-            last, jnp.asarray(np.maximum(lengths - 1, 0)),
-            jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps),
-            jnp.asarray(seeds), do_sample, want_alt)
-        out = (np.asarray(first, np.int32)[:n],
-               np.asarray(lp, np.float32)[:n], self._host_alt(alt, n))
+        with span("serve.runner.prefill.stage"):
+            ls = self.suffix_bucket(max(r.suffix_len for r in rows))
+            width = pick_bucket(n, self.width_buckets)
+            toks = np.zeros((width, ls), np.int32)
+            lengths = np.zeros(width, np.int32)
+            cached = np.zeros(width, np.int32)
+            tables = np.full((width, self.max_blocks_per_seq), NULL_BLOCK,
+                             np.int32)
+            slots = np.full(width, self.num_slots, np.int32)  # pad rows drop
+            temps = np.zeros(width, np.float32)
+            topks = np.zeros(width, np.int32)
+            topps = np.ones(width, np.float32)
+            seeds = np.zeros(width, np.int32)
+            for i, r in enumerate(rows):
+                suf = r.tokens[r.start:]
+                toks[i, :len(suf)] = suf
+                lengths[i] = len(r.tokens)
+                cached[i] = r.cached_len
+                tables[i] = r.table_row
+                slots[i] = r.slot
+                temps[i] = r.sampling.temperature
+                topks[i] = r.sampling.top_k
+                topps[i] = r.sampling.top_p
+                seeds[i] = sampling.seed32(r.sampling.seed)
+            self.prefill_shapes.add((width, ls))
+            self.prefill_dispatches += 1
+            self.prefill_padded_tokens += width * ls
+            self.prefill_computed_tokens += sum(r.suffix_len for r in rows)
+            # analytic peak attention-score bytes for this dispatch: the
+            # streamed path (attention.streamed_paged_attention) bounds
+            # the pool band at attn_chunk keys, plus the (ls, ls) suffix
+            # tile — f32 scores per head. Benchmarks assert this stays
+            # flat as the prompt grows past the chunk budget.
+            kv_band = min(self.max_blocks_per_seq * self.block_size,
+                          self.cfg.attn_chunk)
+            score_bytes = (4 * width * self.cfg.n_heads * ls
+                           * (kv_band + ls))
+            self.prefill_peak_score_bytes = max(
+                self.prefill_peak_score_bytes, score_bytes)
+            do_sample = bool(temps.max() > 0.0)
+            want_alt = any(r.sampling.logprobs for r in rows)
+            args = [jnp.asarray(a)
+                    for a in (toks, lengths, cached, tables, slots)]
+            first_args = [jnp.asarray(a) for a in (
+                np.maximum(lengths - 1, 0), temps, topks, topps, seeds)]
+        last, self.state = self._prefill_fn(self.params, self.state, *args,
+                                            resume)
+        first, lp, alt = self._first_fn(last, *first_args, do_sample,
+                                        want_alt)
+        with span("serve.runner.prefill.fetch"):
+            out = (np.asarray(first, np.int32)[:n],
+                   np.asarray(lp, np.float32)[:n], self._host_alt(alt, n))
         if obs.enabled:
             variant = (width, ls, do_sample, want_alt, resume)
             self._c_prefill.inc()
@@ -471,6 +475,7 @@ class ModelRunner:
         lps = np.asarray(lps, np.float32)
         return (ids[:n], lps[:n]) if n is not None else (ids, lps)
 
+    @spanned("serve.runner.decode")
     def decode(self, tokens: np.ndarray, positions: np.ndarray):
         """One batched decode step over all lanes. tokens/positions:
         (num_slots,) int32 host arrays. Returns ((num_slots,) int32
@@ -479,16 +484,17 @@ class ModelRunner:
         logprobs) when any live slot asked for alternatives)."""
         obs = self._obs
         t0 = self._now() if obs.enabled else 0.0
-        do_sample = self.any_sampled
-        if do_sample:
-            self.sampled_dispatches += 1
-        want_alt = self.any_alt
-        temps, topks, topps, seeds = self._sampling_device()
+        with span("serve.runner.decode.stage"):
+            do_sample = self.any_sampled
+            if do_sample:
+                self.sampled_dispatches += 1
+            want_alt = self.any_alt
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    self._tables_device(), *self._sampling_device())
         next_tok, lp, alt, self.state = self._decode_fn(
-            self.params, self.state, jnp.asarray(tokens),
-            jnp.asarray(positions), self._tables_device(), temps, topks,
-            topps, seeds, do_sample, want_alt)
-        out = np.asarray(next_tok), np.asarray(lp), self._host_alt(alt)
+            self.params, self.state, *args, do_sample, want_alt)
+        with span("serve.runner.decode.fetch"):
+            out = np.asarray(next_tok), np.asarray(lp), self._host_alt(alt)
         if obs.enabled:
             variant = (do_sample, want_alt)
             self._c_decode.inc()
@@ -498,6 +504,7 @@ class ModelRunner:
             self._decode_variants.add(variant)
         return out
 
+    @spanned("serve.runner.verify")
     def verify(self, tokens: np.ndarray, positions: np.ndarray,
                counts: np.ndarray):
         """One batched multi-token verify dispatch. tokens: (num_slots,
@@ -519,18 +526,19 @@ class ModelRunner:
         self.verify_dispatches += 1
         self.verify_padded_tokens += tokens.shape[0] * T
         self.verify_chain_tokens += int(counts.sum())
-        do_sample = self.any_sampled
-        if do_sample:
-            self.sampled_dispatches += 1
-        want_alt = self.any_alt
-        temps, topks, topps, seeds = self._sampling_device()
+        with span("serve.runner.verify.stage"):
+            do_sample = self.any_sampled
+            if do_sample:
+                self.sampled_dispatches += 1
+            want_alt = self.any_alt
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(counts), self._tables_device(),
+                    *self._sampling_device())
         emit, accept, lp, alt, self.state, self._snaps = self._verify_fn(
-            self.params, self.state, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(counts),
-            self._tables_device(), temps, topks, topps, seeds, do_sample,
-            want_alt)
-        out = (np.asarray(emit), np.asarray(accept), np.asarray(lp),
-               self._host_alt(alt))
+            self.params, self.state, *args, do_sample, want_alt)
+        with span("serve.runner.verify.fetch"):
+            out = (np.asarray(emit), np.asarray(accept), np.asarray(lp),
+                   self._host_alt(alt))
         if obs.enabled:
             variant = (T, do_sample, want_alt)
             self._c_verify.inc()
@@ -553,6 +561,7 @@ class ModelRunner:
                                          jnp.asarray(idx))
         self._snaps = None
 
+    @spanned("serve.runner.copy_block")
     def copy_block(self, src: int, dst: int) -> None:
         """Device-side copy-on-write: clone block `src`'s K/V into `dst`
         in every attention pool."""

@@ -86,7 +86,7 @@ import numpy as np
 from repro.serving.block_manager import (NULL_BLOCK, BlockAllocator,
                                          PrefixMatch)
 from repro.serving.draft import make_proposer
-from repro.serving.observability import NULL_OBS, Observability
+from repro.serving.observability import NULL_OBS, Observability, spanned
 from repro.serving.runner import ModelRunner, PrefillRow
 from repro.serving.sampling import SamplingParams, resolve
 from repro.serving.slo import SLO_TID, SLOTracker
@@ -343,6 +343,9 @@ class Scheduler:
         self.resumes = 0              # preempted lanes re-admitted
         self.shed_requests = 0        # SLO-shed before admission
         self.deferrals = 0            # requests EDF-deferred at least once
+        # (t_admit, t_admit - arrival) of each fresh admission, on the
+        # engine clock; resumed lanes are not counted again
+        self.queue_waits: List[Tuple[float, float]] = []
 
     # ------------------------------------------------------------------
     # queue
@@ -514,9 +517,12 @@ class Scheduler:
         if cached > 0:
             self.prefix_hit_requests += 1
             self.allocator.touch(match.full_blocks)
+        t_admit = self._now()
+        if req.rid not in self._resume_state:
+            self.queue_waits.append((t_admit, t_admit - req.arrival))
         return _Plan(req=req, table_row=row, slot=slot, cached=cached,
                      n_blocks=n_prompt, budget=budget, cow_block=cow_block,
-                     cow_index=cow_index, t_admit=self._now())
+                     cow_index=cow_index, t_admit=t_admit)
 
     def _defer_for_group_prefix(self, req: Request, match: PrefixMatch,
                                 plans: List[_Plan]) -> bool:
@@ -688,6 +694,7 @@ class Scheduler:
                                            -self._slots[i].t_admit))
         return self.preempt(victim) is not None
 
+    @spanned("serve.scheduler.admit")
     def admit(self) -> None:
         """Form same-bucket groups from the queue — scanned in
         (effective priority desc, submit order), see `_eff_priority` —
@@ -849,6 +856,7 @@ class Scheduler:
             prefill_chunks_total=-(-(P - start) // chunk))
         self._slots[p.slot] = s
 
+    @spanned("serve.scheduler.prefill_step")
     def prefill_step(self) -> bool:
         """Advance ONE in-flight chunked prefill by one chunk (round-
         robin across slots so concurrent long admissions share the
@@ -1141,6 +1149,7 @@ class Scheduler:
     # decode-side lifecycle
     # ------------------------------------------------------------------
 
+    @spanned("serve.scheduler.prepare_decode")
     def prepare_decode(self):
         """Assemble the plain one-token decode batch; fire pending lazy
         copy-on-writes and claim the block each lane's write needs.
@@ -1161,6 +1170,7 @@ class Scheduler:
             positions[i] = s.pos
         return tokens, positions, active
 
+    @spanned("serve.scheduler.consume")
     def consume(self, active: List[int], next_tok: np.ndarray,
                 lp: Optional[np.ndarray] = None, alt=None) -> None:
         """Advance each active lane with its sampled token; finish and
@@ -1184,6 +1194,7 @@ class Scheduler:
     # speculative decoding: propose -> verify -> accept / rollback
     # ------------------------------------------------------------------
 
+    @spanned("serve.scheduler.prepare_verify")
     def prepare_verify(self):
         """Assemble a verify batch of per-lane draft chains
         [pending, d_1 .. d_k] (k from each lane's proposer, capped so
@@ -1228,6 +1239,7 @@ class Scheduler:
             self._last_proposed = {i: len(drafts[i]) for i in active}
         return tokens, positions, counts, active
 
+    @spanned("serve.scheduler.consume_verify")
     def consume_verify(self, active: List[int], out_tok: np.ndarray,
                        accept: np.ndarray,
                        lp: Optional[np.ndarray] = None, alt=None) -> None:

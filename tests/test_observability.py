@@ -7,6 +7,7 @@ bench_compare regression flagging."""
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -343,6 +344,157 @@ def test_cluster_trace_scopes_replicas(tiny):
         assert r.trace["queued"] <= r.trace["routed"]
     sc = summarize_cluster(done, router.wall_time, router)
     assert sc["cluster"]["replicas"] == 2
+
+
+# ----------------------------------------------------------------------------
+# profiler spans: the serving stack's own spans in jax.profiler's trace
+# ----------------------------------------------------------------------------
+
+PLAIN = dict(KW, speculate=0)
+
+
+def _host_spans(trace_dir) -> dict:
+    """serve.* events of the trace's host plane: {thread line: [(name,
+    start_ns, end_ns), ...]} sorted by start, outer spans first."""
+    import glob as glob_lib
+
+    from jax.profiler import ProfileData
+    path, = glob_lib.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith("serve.")]
+            if evs:
+                out[line.name] = sorted(evs, key=lambda e: (e[1], -e[2]))
+    return out
+
+
+def _inside(ev, outer) -> bool:
+    return outer[1] <= ev[1] and ev[2] <= outer[2]
+
+
+@pytest.fixture(scope="module")
+def profiled_run(tiny, tmp_path_factory):
+    """The same workload served without and then under `jax.profiler`,
+    with one forced collection inside the trace."""
+    import gc
+
+    params, cfg = tiny
+    reqs = _reqs(cfg, n=5, seed=11)
+    eng = ServingEngine(params, cfg, **PLAIN)
+    ref = eng.run(list(reqs))
+    d = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(d)):
+        done = eng.run(list(reqs))
+        gc.collect()
+    return eng, done, ref, reqs, _host_spans(d)
+
+
+def test_profiler_spans_nest_where_the_work_happens(profiled_run):
+    from repro.serving.observability import SPANS
+
+    eng, _, _, _, lines = profiled_run
+    evs = [e for line in lines.values() for e in line]
+    assert {n for n, _, _ in evs} <= set(SPANS)
+    steps = [e for e in evs if e[0] == "serve.step"]
+    assert len(steps) == eng.steps > 0
+    assert any(n == "serve.host.gc" for n, _, _ in evs)
+    for st in steps:
+        inner = [e for e in evs if e is not st and _inside(e, st)]
+        names = [n for n, _, _ in inner]
+        for want in ("serve.scheduler.prepare_decode",
+                     "serve.scheduler.consume", "serve.runner.decode"):
+            assert names.count(want) == 1, (want, names)
+        dec, = [e for e in inner if e[0] == "serve.runner.decode"]
+        stage, = [e for e in inner if e[0] == "serve.runner.decode.stage"]
+        fetch, = [e for e in inner if e[0] == "serve.runner.decode.fetch"]
+        assert _inside(stage, dec) and _inside(fetch, dec)
+        assert stage[2] <= fetch[1]
+        prep, = [e for e in inner
+                 if e[0] == "serve.scheduler.prepare_decode"]
+        cons, = [e for e in inner if e[0] == "serve.scheduler.consume"]
+        assert prep[2] <= dec[1] and dec[2] <= cons[1]
+        # every prefill dispatch sits inside the scheduler phase that
+        # asked for it, with its own stage and fetch
+        for pre in (e for e in inner if e[0] == "serve.runner.prefill"):
+            assert any(_inside(pre, e) for e in inner if e[0] in (
+                "serve.scheduler.admit", "serve.scheduler.prefill_step"))
+            kids = [n for n, *_ in (e for e in inner
+                                    if e is not pre and _inside(e, pre))]
+            assert {"serve.runner.prefill.stage",
+                    "serve.runner.prefill.fetch"} <= set(kids)
+
+
+def test_profiler_on_output_bit_identical(profiled_run):
+    _, done, ref, _, _ = profiled_run
+    by_rid = {c.rid: c for c in ref}
+    assert {c.rid for c in done} == set(by_rid)
+    for c in done:
+        np.testing.assert_array_equal(c.tokens, by_rid[c.rid].tokens)
+
+
+def test_queue_waits_one_per_fresh_admission(profiled_run):
+    eng, done, _, reqs, _ = profiled_run
+    waits = eng.scheduler.queue_waits
+    assert len(waits) == len(reqs)
+    by_rid = {c.rid: c for c in done}
+    assert sorted(t for t, _ in waits) == sorted(
+        c.t_admit for c in by_rid.values())
+    assert all(w >= 0 and t >= w for t, w in waits)
+    # two lanes for five requests: the later ones waited
+    assert max(w for _, w in waits) > 0
+    eng.begin_run()
+    assert eng.scheduler.queue_waits == []
+
+
+def test_queue_waits_leave_out_resumed_lanes(tiny):
+    params, cfg = tiny
+    reqs = _reqs(cfg, n=3, seed=13)
+    eng = ServingEngine(params, cfg, **PLAIN)
+    eng.begin_run()
+    for r in reqs:
+        eng.submit(r)
+    eng.step()
+    assert eng.scheduler.preempt() is not None
+    while eng.has_work:
+        eng.step()
+    assert eng.scheduler.resumes == 1
+    assert len(eng.scheduler.queue_waits) == len(reqs)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "first"])
+def test_runner_program_names(tiny, program):
+    """The chip benchmark finds each dispatch in the device trace by its
+    module name (`jit__decode`, `jit__prefill`, `jit__first`); a renamed
+    jitted function would silently empty those readings."""
+    import re
+
+    from repro.serving.runner import ModelRunner
+
+    params, cfg = tiny
+    slots, mb = 2, 4
+    runner = ModelRunner(params, cfg, num_slots=slots, block_size=4,
+                         num_blocks=1 + slots * mb, max_blocks_per_seq=mb)
+    z = jnp.zeros(slots, jnp.int32)
+    sampling_args = runner._sampling_device()
+    if program == "decode":
+        lowered = runner._decode_fn.lower(
+            runner.params, runner.state, z, z,
+            jnp.zeros((slots, mb), jnp.int32), *sampling_args, False,
+            False)
+    elif program == "prefill":
+        lowered = runner._prefill_fn.lower(
+            runner.params, runner.state, jnp.zeros((slots, 16), jnp.int32),
+            z, z, jnp.zeros((slots, mb), jnp.int32), z, False)
+    else:
+        lowered = runner._first_fn.lower(
+            jnp.zeros((slots, cfg.vocab_size), jnp.float32), z,
+            *sampling_args, False, False)
+    name = re.match(r"module @(\S+)", lowered.as_text()).group(1)
+    assert name == f"jit__{program}"
 
 
 # ----------------------------------------------------------------------------
