@@ -21,8 +21,9 @@ NEG_INF = -1e30
 # over head_dim — the optim/compression.py quantizer shape, localized per
 # pool slot so each write (prefill/decode/verify) quantizes independently
 # and copying a block's (q, scale) pair verbatim is an exact round-trip.
-# Pool layer dicts carry "k_scale"/"v_scale" side-tables when quantized;
-# consumers detect that by key presence, which is shape-static under jit.
+# Quantized pool layer dicts carry a "kv_scale" side-table (see the row
+# form below); consumers detect it by key presence, which is shape-static
+# under jit.
 # ----------------------------------------------------------------------------
 
 _POOL_QMAX = {jnp.dtype(jnp.int8): 127.0}
@@ -276,20 +277,89 @@ def decode_attention_block(params, x, cache, pos, cfg, *, window: int = 0):
 
 
 # ----------------------------------------------------------------------------
+# Paged KV pools in row form
+#
+# A paged attention layer keeps its KV in one array of token rows,
+# cache["kv"]: (num_blocks * block_size, 2 * KV * hd). Row
+# block * block_size + offset holds one token: its K in the first KV * hd
+# columns, its V in the last. The scanned layer stack carries one such
+# array per pattern position with a leading n_super axis through the
+# layer loop, and each layer reads and writes it at [layer, rows], so no
+# program slices, relayouts or rebuilds the pool to touch one layer
+# (`layer` is None for an unstacked pool). Quantized pools add
+# cache["kv_scale"]: (..., num_blocks * block_size, 2 * KV) float32, the
+# K heads' scales then the V heads'.
+#
+# On TPU the default layout of a row pool is row-major with no padding
+# when the row width is a multiple of 128 lanes, as it is for every
+# published config (384 for smollm-135m, 5120 for stablelm-3b). A row
+# width that is not (a 192-wide pool, compiled for a v5e) gets a
+# transposed default layout and whole-pool copies at each program's entry
+# and exit; such a config would need the pool's layout pinned
+# (jax.experimental.layout.Format) in every program that takes it.
+# tests/test_tpu_compile.py checks the 384-wide case.
+# ----------------------------------------------------------------------------
+
+def _gather_blocks(pool, layer, block_tables, block_size):
+    """The rows of every block in `block_tables` (..., M), read in place
+    from `pool` (rows, C) or, with `layer`, from the stacked (n_super,
+    rows, C). Returns (..., M * block_size, C).
+
+    A block's rows are contiguous, so this gathers whole (block_size, C)
+    slices through a (.., blocks, block_size, C) view of the pool (on a
+    v5e a 30-layer decode-shaped loop took 10.3 ms so, 33.6 ms gathering
+    the same rows one by one). The view is free (a bitcast) while
+    block_size is a multiple of the TPU's 8-row tile, as the served
+    block size of 16 is."""
+    view = pool.reshape(*pool.shape[:-2], -1, block_size, pool.shape[-1])
+    g = view.at[(block_tables,) if layer is None else (layer, block_tables)
+                ].get(mode="clip")
+    return g.reshape(*block_tables.shape[:-1], -1, pool.shape[-1])
+
+
+def read_kv(cache, layer, block_tables, block_size, head_dim):
+    """K and V of every token in the blocks of `block_tables` (..., M):
+    two (..., M * block_size, KV, hd) arrays, in the pool dtype, or
+    dequantized to float32 for a quantized pool."""
+    kv = _gather_blocks(cache["kv"], layer, block_tables, block_size)
+    kv = kv.reshape(*kv.shape[:-1], -1, head_dim)        # (..., S, 2KV, hd)
+    if "kv_scale" in cache:
+        kv = dequantize_kv(kv, _gather_blocks(cache["kv_scale"], layer,
+                                              block_tables, block_size))
+    n_kv = kv.shape[-2] // 2
+    return kv[..., :n_kv, :], kv[..., n_kv:, :]
+
+
+def write_kv(cache, layer, rows, k, v):
+    """Land tokens' K and V (each (..., KV, hd)) at pool rows `rows` (...),
+    quantizing on landing for a quantized pool. Returns the new cache."""
+    kv = jnp.concatenate([k, v], axis=-2)                # (..., 2KV, hd)
+    at = (rows,) if layer is None else (layer, rows)
+    new_cache = dict(cache)
+    if "kv_scale" in cache:
+        kv, scale = quantize_kv(kv, cache["kv"].dtype)
+        new_cache["kv_scale"] = cache["kv_scale"].at[at].set(scale)
+    new_cache["kv"] = cache["kv"].at[at].set(
+        kv.reshape(*kv.shape[:-2], -1).astype(cache["kv"].dtype))
+    return new_cache
+
+
+# ----------------------------------------------------------------------------
 # Paged KV-cache prefill (bucketed batched admission over cached prefixes)
 # ----------------------------------------------------------------------------
 
 def streamed_paged_attention(q, k, v, cache, block_tables, positions,
                              starts, lengths, *, scale, attn_chunk,
-                             window: int = 0):
+                             block_size, window: int = 0, layer=None):
     """Online-softmax suffix-prefill attention over a paged KV cache.
 
     q: (N, Ls, H, hd) rope'd suffix queries; k/v: (N, Ls, KV, hd) rope'd
-    suffix keys/values (NOT yet scattered into the pools); cache k/v:
-    (P, bs, KV, hd) physical block pools holding the cached prefix;
-    block_tables: (N, M); positions: (N, Ls) absolute query positions;
-    starts/lengths: (N,) — queries attend to pool positions < starts and
-    causally within the suffix (suffix index < lengths - starts).
+    suffix keys/values (NOT yet written to the pool); cache: a row-form
+    pool (see above; `layer` picks a layer of a stacked one) holding the
+    cached prefix; block_tables: (N, M); positions: (N, Ls) absolute
+    query positions; starts/lengths: (N,) — queries attend to pool
+    positions < starts and causally within the suffix (suffix index <
+    lengths - starts).
 
     The pool is streamed in bands of ceil(attn_chunk / bs) blocks via
     lax.scan, folding each band into flash running stats (_attn_stats /
@@ -300,7 +370,7 @@ def streamed_paged_attention(q, k, v, cache, block_tables, positions,
     Returns the normalized attention output (N, Ls, H, hd) float32.
     """
     N, Ls, H, hd = q.shape
-    bs = cache["k"].shape[1]
+    bs = block_size
     M = block_tables.shape[1]
 
     # suffix: fresh q vs fresh k/v, causal within the suffix window
@@ -323,17 +393,9 @@ def streamed_paged_attention(q, k, v, cache, block_tables, positions,
         bt = jnp.pad(bt, ((0, 0), (0, nb * cb - M)))
     bt = bt.reshape(N, nb, cb).transpose(1, 0, 2)            # (nb, N, cb)
 
-    quant = "k_scale" in cache
-
     def band(stats, inp):
         bi, btc = inp                                        # btc: (N, cb)
-        gk = cache["k"][btc].reshape(N, cb * bs, *cache["k"].shape[2:])
-        gv = cache["v"][btc].reshape(N, cb * bs, *cache["v"].shape[2:])
-        if quant:
-            gk = dequantize_kv(
-                gk, cache["k_scale"][btc].reshape(N, cb * bs, -1))
-            gv = dequantize_kv(
-                gv, cache["v_scale"][btc].reshape(N, cb * bs, -1))
+        gk, gv = read_kv(cache, layer, btc, bs, hd)          # (N, cb*bs, ..)
         kpos = bi * cb * bs + jnp.arange(cb * bs)
         m = (kpos[None, None, :] < starts[:, None, None])    # (N, 1, cb*bs)
         if window > 0:
@@ -352,7 +414,7 @@ def streamed_paged_attention(q, k, v, cache, block_tables, positions,
 
 def paged_prefill_attention_block(params, x, cache, positions, block_tables,
                                   starts, lengths, cached_lens, cfg, *,
-                                  window: int = 0):
+                                  block_size, window: int = 0, layer=None):
     """Suffix prefill for a batch of sequences straight into paged KV.
 
     x: (N, Ls, D) — each row is one sequence's prompt SUFFIX (tokens from
@@ -362,11 +424,11 @@ def paged_prefill_attention_block(params, x, cache, positions, block_tables,
     at lengths-1 so at least one token is always computed);
     lengths: (N,) true prompt lengths; cached_lens: (N,) tokens whose KV
     already sits in the sequence's blocks (scatter skips them);
-    block_tables: (N, max_blocks); cache k/v: physical block pools.
+    block_tables: (N, max_blocks); cache: a row-form pool, at `layer`.
 
     Queries attend to the cached prefix (streamed through the block
     table, masked to kpos < starts) plus the suffix causally; the
-    suffix's rope'd K/V is scattered into (table[p // bs], p % bs) for
+    suffix's rope'd K/V lands at row table[p // bs] * bs + p % bs for
     cached_lens <= p < lengths — padded and already-cached positions are
     redirected to the null block.
 
@@ -381,11 +443,12 @@ def paged_prefill_attention_block(params, x, cache, positions, block_tables,
     N, Ls, D = x.shape
     q, k, v = _qkv(params, x, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
                    positions, cfg.rope_theta)
-    bs = cache["k"].shape[1]
+    bs = block_size
     M = block_tables.shape[1]
     o = streamed_paged_attention(q, k, v, cache, block_tables, positions,
                                  starts, lengths, scale=cfg.head_dim ** -0.5,
-                                 attn_chunk=cfg.attn_chunk, window=window)
+                                 attn_chunk=cfg.attn_chunk, block_size=bs,
+                                 window=window, layer=layer)
     out = (o.reshape(N, Ls, -1) @ params["wo"]).astype(x.dtype)
 
     write = jnp.logical_and(positions >= cached_lens[:, None],
@@ -393,16 +456,8 @@ def paged_prefill_attention_block(params, x, cache, positions, block_tables,
     blk = jnp.take_along_axis(block_tables,
                               jnp.clip(positions // bs, 0, M - 1), axis=1)
     blk = jnp.where(write, blk, 0)               # null-sink the rest
-    off = positions % bs
-    new_cache = dict(cache)
-    if "k_scale" in cache:                       # quantize on landing
-        k, sk = quantize_kv(k, cache["k"].dtype)
-        v, sv = quantize_kv(v, cache["v"].dtype)
-        new_cache["k_scale"] = cache["k_scale"].at[blk, off].set(sk)
-        new_cache["v_scale"] = cache["v_scale"].at[blk, off].set(sv)
-    new_cache["k"] = cache["k"].at[blk, off].set(k)
-    new_cache["v"] = cache["v"].at[blk, off].set(v)
-    return out, new_cache
+    rows = blk * bs + positions % bs
+    return out, write_kv(cache, layer, rows, k, v)
 
 
 # ----------------------------------------------------------------------------
@@ -410,18 +465,19 @@ def paged_prefill_attention_block(params, x, cache, positions, block_tables,
 # ----------------------------------------------------------------------------
 
 def paged_decode_attention_block(params, x, cache, positions, block_tables,
-                                 cfg, *, window: int = 0):
+                                 cfg, *, block_size, window: int = 0,
+                                 layer=None):
     """One-token decode through a paged KV cache (serving/kv_cache.py).
 
     x: (B, 1, D) — one token per slot, B = number of decode slots;
-    cache k/v: (num_blocks, block_size, KV, hd) physical block pools shared
-    by all slots; positions: (B,) int32 per-slot token positions (ragged —
-    each slot is at its own depth); block_tables: (B, max_blocks) int32.
+    cache: a row-form pool shared by all slots (at `layer` of a stacked
+    one); positions: (B,) int32 per-slot token positions (ragged — each
+    slot is at its own depth); block_tables: (B, max_blocks) int32.
 
-    The current token's K/V is scattered into (block_tables[b, p//bs],
-    p % bs); scores are gathered back through the table. Slots whose table
-    rows point at the reserved null block write garbage there and mask it
-    out — inactive slots cost nothing but the batch lane.
+    The current token's K/V lands at row block_tables[b, p//bs] * bs +
+    p % bs; keys and values are gathered back through the table. Slots
+    whose table rows point at the reserved null block write garbage there
+    and mask it out — inactive slots cost nothing but the batch lane.
 
     window > 0 masks to the trailing `window` positions (local attention
     keeps the full paged history; the mask, not a rolling buffer, bounds
@@ -431,26 +487,10 @@ def paged_decode_attention_block(params, x, cache, positions, block_tables,
     B, _, D = x.shape
     q, k, v = _qkv(params, x, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
                    positions[:, None], cfg.rope_theta)
-    bs = cache["k"].shape[1]
-    blk = block_tables[jnp.arange(B), positions // bs]
-    off = positions % bs
-    new_cache = dict(cache)
-    kw, vw = k[:, 0], v[:, 0]
-    if "k_scale" in cache:                       # quantize on landing
-        kw, sk = quantize_kv(kw, cache["k"].dtype)
-        vw, sv = quantize_kv(vw, cache["v"].dtype)
-        new_cache["k_scale"] = cache["k_scale"].at[blk, off].set(sk)
-        new_cache["v_scale"] = cache["v_scale"].at[blk, off].set(sv)
-    ck = cache["k"].at[blk, off].set(kw)
-    cv = cache["v"].at[blk, off].set(vw)
-
-    gk = ck[block_tables].reshape(B, -1, *ck.shape[2:])  # (B, M*bs, KV, hd)
-    gv = cv[block_tables].reshape(B, -1, *cv.shape[2:])
-    if "k_scale" in cache:                       # dequantize the gather
-        gk = dequantize_kv(gk, new_cache["k_scale"][block_tables]
-                           .reshape(B, -1, ck.shape[2]))
-        gv = dequantize_kv(gv, new_cache["v_scale"][block_tables]
-                           .reshape(B, -1, cv.shape[2]))
+    bs = block_size
+    rows = block_tables[jnp.arange(B), positions // bs] * bs + positions % bs
+    new_cache = write_kv(cache, layer, rows, k[:, 0], v[:, 0])
+    gk, gv = read_kv(new_cache, layer, block_tables, bs, cfg.head_dim)
     s = _gqa_scores(q, gk) * (cfg.head_dim ** -0.5)      # (B, H, 1, M*bs)
     kpos = jnp.arange(gk.shape[1])
     valid = kpos[None, :] <= positions[:, None]
@@ -460,5 +500,4 @@ def paged_decode_attention_block(params, x, cache, positions, block_tables,
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = _gqa_out(p, gv).reshape(B, 1, -1)
-    new_cache["k"], new_cache["v"] = ck, cv
     return (o @ params["wo"]).astype(x.dtype), new_cache

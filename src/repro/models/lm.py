@@ -14,15 +14,16 @@ Entry points:
          batch may carry "lengths" for right-padded mixed-length rows)
   init_decode_state(cfg, batch, max_len)      -> state pytree
   decode_step(params, cfg, state, tokens, pos)-> logits, new state (decode)
-  decode_step_paged(params, cfg, state, tokens, positions, block_tables)
+  decode_step_paged(params, cfg, state, tokens, positions, block_tables,
+                    block_size)
       -> logits, new state    (continuous-batching decode over paged KV;
          see serving/ for slot scheduling and block allocation)
   prefill_paged(params, cfg, state, tokens, lengths, cached_lens,
-                block_tables, slots)
+                block_tables, slots, block_size)
       -> last_logits, new state   (bucketed batched prefill straight into
          paged state, skipping prefix-cached tokens; see serving/runner)
   decode_verify_paged(params, cfg, state, tokens, positions, counts,
-                      block_tables)
+                      block_tables, block_size)
       -> per-position logits, new state, recurrent snapshots
          (batched K-token verify forward for speculative decoding;
          commit_decode_state(cfg, state, snapshots, idx) accepts/rolls
@@ -478,17 +479,70 @@ def decode_step(params, cfg: ModelConfig, state, tokens, pos):
 # Paged decode (continuous-batching serving: per-slot ragged positions)
 # ----------------------------------------------------------------------------
 
+# block kinds whose KV lives in the paged pools
+ATTN_KINDS = ("attn", "attn_local", "moe")
+
+
+def _paged_stack(params, cfg: ModelConfig, state, h, apply):
+    """Runs the prefix layers and the superblock scan over paged state.
+
+    apply(block_params, kind, h, layer_state, layer) -> (h, new_state,
+    snapshot-or-None) is one layer; `layer` is the scan index for a
+    stacked attention pool and None otherwise. The stacked attention
+    pools (see attention.py's row form) ride in the scan CARRY, so each
+    layer updates its rows in place and nothing copies a pool; recurrent
+    slot state, small and per layer, stays in xs/ys. Returns (h,
+    new_state, snapshots) with snapshots mirroring the state tree."""
+    new_prefix, prefix_snaps = [], []
+    for p, kind, st in zip(params["prefix"], cfg.prefix_pattern,
+                           state["prefix"]):
+        h, st, snap = apply(p, kind, h, st, None)
+        new_prefix.append(st)
+        prefix_snaps.append(snap)
+
+    pooled = {f"p{pi}" for pi, kind in enumerate(cfg.block_pattern)
+              if kind in ATTN_KINDS}
+    pools = {k: v for k, v in state["blocks"].items() if k in pooled}
+    slot_state = {k: v for k, v in state["blocks"].items()
+                  if k not in pooled}
+
+    def superblock(carry, xs):
+        h, pools = carry
+        block_params, slot_state, layer = xs
+        block_params = _pin_block(block_params)
+        h = _pin_act(h)
+        pools = dict(pools)
+        new_state, snaps = {}, {}
+        for pi, kind in enumerate(cfg.block_pattern):
+            key = f"p{pi}"
+            if key in pools:
+                h, pools[key], _ = apply(block_params[key], kind, h,
+                                         pools[key], layer)
+            else:
+                h, new_state[key], snaps[key] = apply(
+                    block_params[key], kind, h, slot_state[key], None)
+        return (h, pools), (new_state, snaps)
+
+    (h, pools), (new_slot, block_snaps) = lax.scan(
+        superblock, (h, pools),
+        (params["blocks"], slot_state,
+         jnp.arange(cfg.n_super, dtype=jnp.int32)))
+    return (h, {"prefix": new_prefix, "blocks": {**new_slot, **pools}},
+            {"prefix": prefix_snaps, "blocks": block_snaps})
+
+
 def _apply_block_step_paged(params, kind: str, x, positions,
-                            cfg: ModelConfig, state, block_tables):
+                            cfg: ModelConfig, state, block_tables,
+                            block_size: int, layer=None):
     """One-token step against paged attention state. x: (B,1,D);
     positions: (B,) per-slot. Non-attention layers keep slot-indexed dense
     state (O(B) per layer) and ignore positions."""
-    if kind in ("attn", "attn_local", "moe"):
+    if kind in ATTN_KINDS:
         h = rms_norm(x, params["norm1"], cfg.norm_eps)
         window = cfg.window if kind == "attn_local" else 0
         o, new_cache = attention.paged_decode_attention_block(
             params["attn"], h, state, positions, block_tables, cfg,
-            window=window)
+            block_size=block_size, window=window, layer=layer)
         x = x + o
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
         if kind == "moe":
@@ -504,8 +558,9 @@ def _apply_block_step_paged(params, kind: str, x, positions,
 
 def _apply_block_prefill_paged(params, kind: str, x, positions,
                                cfg: ModelConfig, state, block_tables,
-                               starts, lengths, cached_lens, slots,
-                               resume: bool = False):
+                               block_size: int, starts, lengths,
+                               cached_lens, slots, resume: bool = False,
+                               layer=None):
     """Batched suffix-prefill against paged state. x: (N, Ls, D).
 
     Attention layers attend to their cached prefix through the block
@@ -517,12 +572,13 @@ def _apply_block_prefill_paged(params, kind: str, x, positions,
     cached_lens = 0 for them); resume=True (a chunked-prefill
     continuation) gathers each row's initial recurrent state from its
     slot, where the previous chunk's dispatch scattered it."""
-    if kind in ("attn", "attn_local", "moe"):
+    if kind in ATTN_KINDS:
         h = rms_norm(x, params["norm1"], cfg.norm_eps)
         window = cfg.window if kind == "attn_local" else 0
         o, new_cache = attention.paged_prefill_attention_block(
             params["attn"], h, state, positions, block_tables, starts,
-            lengths, cached_lens, cfg, window=window)
+            lengths, cached_lens, cfg, block_size=block_size, window=window,
+            layer=layer)
         x = x + o
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
         if kind == "moe":
@@ -547,7 +603,8 @@ def _apply_block_prefill_paged(params, kind: str, x, positions,
 
 
 def prefill_paged(params, cfg: ModelConfig, state, tokens, lengths,
-                  cached_lens, block_tables, slots, resume: bool = False):
+                  cached_lens, block_tables, slots, block_size: int,
+                  resume: bool = False):
     """Bucketed batched prefill straight into the paged serving state.
 
     tokens: (N, Ls) int32 — row n holds the prompt SUFFIX starting at
@@ -559,10 +616,11 @@ def prefill_paged(params, cfg: ModelConfig, state, tokens, lengths,
     block_tables: (N, max_blocks) int32; slots: (N,) decode-slot index
     per row (recurrent dense state lands there; pass num_slots to drop,
     e.g. for batch-padding rows, which should also use lengths = 0 and
-    all-null table rows). resume=True marks a chunked-prefill
-    continuation: recurrent layers pick their initial state up from the
-    slot instead of starting fresh (attention layers resume through
-    cached_lens either way). Must be a static jit argument.
+    all-null table rows); block_size: tokens per block of the pools.
+    resume=True marks a chunked-prefill continuation: recurrent layers
+    pick their initial state up from the slot instead of starting fresh
+    (attention layers resume through cached_lens either way). Must be a
+    static jit argument.
 
     One jitted instance serves every batch whose (N, Ls) matches — the
     scheduler buckets suffix lengths into powers of two precisely so the
@@ -578,39 +636,23 @@ def prefill_paged(params, cfg: ModelConfig, state, tokens, lengths,
     positions = starts[:, None] + jnp.arange(Ls, dtype=jnp.int32)[None, :]
     h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.act_dtype)
 
-    new_prefix = []
-    for p, kind, st in zip(params["prefix"], cfg.prefix_pattern,
-                           state["prefix"]):
-        h, st_new = _apply_block_prefill_paged(
-            p, kind, h, positions, cfg, st, block_tables, starts, lengths,
-            cached_lens, slots, resume=resume)
-        new_prefix.append(st_new)
+    def apply(p, kind, h, st, layer):
+        h, st = _apply_block_prefill_paged(
+            p, kind, h, positions, cfg, st, block_tables, block_size,
+            starts, lengths, cached_lens, slots, resume=resume, layer=layer)
+        return h, st, None
 
-    def superblock(h, xs):
-        block_params, block_state = xs
-        block_params = _pin_block(block_params)
-        h = _pin_act(h)
-        new_state = {}
-        for pi, kind in enumerate(cfg.block_pattern):
-            h, st = _apply_block_prefill_paged(
-                block_params[f"p{pi}"], kind, h, positions, cfg,
-                block_state[f"p{pi}"], block_tables, starts, lengths,
-                cached_lens, slots, resume=resume)
-            new_state[f"p{pi}"] = st
-        return h, new_state
-
-    h, new_blocks = lax.scan(superblock, h,
-                             (params["blocks"], state["blocks"]))
+    h, new_state, _ = _paged_stack(params, cfg, state, h, apply)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = _head(params, cfg, h)                         # (N, Ls, V)
     idx = jnp.clip(lengths - 1 - starts, 0, Ls - 1)
     last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
-    return last, {"prefix": new_prefix, "blocks": new_blocks}
+    return last, new_state
 
 
 def _apply_block_verify_paged(params, kind: str, x, positions,
                               cfg: ModelConfig, state, block_tables,
-                              starts, counts):
+                              block_size: int, starts, counts, layer=None):
     """Batched K-token verify step against paged state. x: (B, T, D);
     row b holds `counts[b]` draft-chain tokens starting at absolute
     position starts[b], right-padded to the bucket length T.
@@ -624,10 +666,10 @@ def _apply_block_verify_paged(params, kind: str, x, positions,
     snapshots instead of committing: the slot state is committed later
     by commit_decode_state at each lane's accepted length. Returns
     (x, new_state, snapshots-or-None)."""
-    if kind in ("attn", "attn_local", "moe"):
+    if kind in ATTN_KINDS:
         x, new_state = _apply_block_prefill_paged(
             params, kind, x, positions, cfg, state, block_tables,
-            starts, starts + counts, starts, None)
+            block_size, starts, starts + counts, starts, None, layer=layer)
         return x, new_state, None
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     if kind == "rwkv":
@@ -654,7 +696,7 @@ def _apply_block_verify_paged(params, kind: str, x, positions,
 
 
 def decode_verify_paged(params, cfg: ModelConfig, state, tokens, positions,
-                        counts, block_tables):
+                        counts, block_tables, block_size: int):
     """Batched K-token verify forward through the paged cache — the
     verify half of the propose/verify speculative-decode pipeline.
 
@@ -662,7 +704,8 @@ def decode_verify_paged(params, cfg: ModelConfig, state, tokens, positions,
     draft_1, ..., draft_{k}] right-padded to the bucket length T;
     positions: (B,) int32 absolute position of each row's first token;
     counts: (B,) int32 true chain lengths (0 = inactive lane: nothing
-    is computed or written for it); block_tables: (B, max_blocks).
+    is computed or written for it); block_tables: (B, max_blocks);
+    block_size: tokens per block of the pools.
 
     Returns (logits (B, T, V) — logits[b, i] are the next-token logits
     after consuming chain token i, exactly what decode_step_paged would
@@ -671,7 +714,7 @@ def decode_verify_paged(params, cfg: ModelConfig, state, tokens, positions,
     scattered eagerly (stale entries from a later-rejected suffix are
     position-masked until overwritten — attention rollback is just not
     advancing the position). Recurrent slot state is NOT advanced:
-    `snapshots` mirrors the recurrent layers of the state tree with
+    `snapshots` holds the recurrent layers of the state tree with
     per-step (T+1, B, ...) stacks (leading n_super axis for scanned
     blocks); commit_decode_state gathers index a+1 per lane to accept
     a draft prefix of length a, or 0 to roll back entirely.
@@ -681,33 +724,15 @@ def decode_verify_paged(params, cfg: ModelConfig, state, tokens, positions,
     pos_grid = positions[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.act_dtype)
 
-    new_prefix, prefix_snaps = [], []
-    for p, kind, st in zip(params["prefix"], cfg.prefix_pattern,
-                           state["prefix"]):
-        h, st_new, snap = _apply_block_verify_paged(
-            p, kind, h, pos_grid, cfg, st, block_tables, positions, counts)
-        new_prefix.append(st_new)
-        prefix_snaps.append(snap)
+    def apply(p, kind, h, st, layer):
+        return _apply_block_verify_paged(p, kind, h, pos_grid, cfg, st,
+                                         block_tables, block_size,
+                                         positions, counts, layer=layer)
 
-    def superblock(h, xs):
-        block_params, block_state = xs
-        block_params = _pin_block(block_params)
-        h = _pin_act(h)
-        new_state, snaps = {}, {}
-        for pi, kind in enumerate(cfg.block_pattern):
-            h, st, snap = _apply_block_verify_paged(
-                block_params[f"p{pi}"], kind, h, pos_grid, cfg,
-                block_state[f"p{pi}"], block_tables, positions, counts)
-            new_state[f"p{pi}"] = st
-            snaps[f"p{pi}"] = snap
-        return h, (new_state, snaps)
-
-    h, (new_blocks, block_snaps) = lax.scan(
-        superblock, h, (params["blocks"], state["blocks"]))
+    h, new_state, snaps = _paged_stack(params, cfg, state, h, apply)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = _head(params, cfg, h)                          # (B, T, V)
-    return (logits, {"prefix": new_prefix, "blocks": new_blocks},
-            {"prefix": prefix_snaps, "blocks": block_snaps})
+    return logits, new_state, snaps
 
 
 def commit_decode_state(cfg: ModelConfig, state, snapshots, idx):
@@ -717,8 +742,9 @@ def commit_decode_state(cfg: ModelConfig, state, snapshots, idx):
     idx: (B,) int32 — tokens of lane b's chain to accept (a+1 for an
     accepted draft prefix of length a, 0 to keep the pre-verify state,
     e.g. for lanes that sat out the dispatch). Attention state needs no
-    commit (positions are the rollback); recurrent leaves are gathered
-    at their lane's accepted snapshot. Returns the committed state."""
+    commit (positions are the rollback) and passes through untouched;
+    recurrent leaves are gathered at their lane's accepted snapshot.
+    Returns the committed state."""
     B = idx.shape[0]
     lanes = jnp.arange(B)
 
@@ -748,37 +774,23 @@ def commit_decode_state(cfg: ModelConfig, state, snapshots, idx):
 
 
 def decode_step_paged(params, cfg: ModelConfig, state, tokens, positions,
-                      block_tables):
+                      block_tables, block_size: int):
     """One decode iteration for a slot batch. tokens: (B,) int32;
     positions: (B,) int32 per-slot token positions (ragged — slots decode
-    independently); block_tables: (B, max_blocks) int32.
+    independently); block_tables: (B, max_blocks) int32; block_size:
+    tokens per block of the pools.
     Returns (logits (B, V), new_state)."""
     params = cast_params(params, cfg)
     h = jnp.take(params["embed"], tokens[:, None],
                  axis=0).astype(cfg.act_dtype)
 
-    new_prefix = []
-    for p, kind, st in zip(params["prefix"], cfg.prefix_pattern,
-                           state["prefix"]):
-        h, st_new = _apply_block_step_paged(p, kind, h, positions, cfg, st,
-                                            block_tables)
-        new_prefix.append(st_new)
+    def apply(p, kind, h, st, layer):
+        h, st = _apply_block_step_paged(p, kind, h, positions, cfg, st,
+                                        block_tables, block_size,
+                                        layer=layer)
+        return h, st, None
 
-    def superblock(h, xs):
-        block_params, block_state = xs
-        block_params = _pin_block(block_params)
-        h = _pin_act(h)
-        new_state = {}
-        for pi, kind in enumerate(cfg.block_pattern):
-            h, st = _apply_block_step_paged(block_params[f"p{pi}"], kind, h,
-                                            positions, cfg,
-                                            block_state[f"p{pi}"],
-                                            block_tables)
-            new_state[f"p{pi}"] = st
-        return h, new_state
-
-    h, new_blocks = lax.scan(superblock, h,
-                             (params["blocks"], state["blocks"]))
+    h, new_state, _ = _paged_stack(params, cfg, state, h, apply)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = _head(params, cfg, h)[:, 0]
-    return logits, {"prefix": new_prefix, "blocks": new_blocks}
+    return logits, new_state

@@ -12,22 +12,27 @@ Block 0 is reserved as the null sink: inactive decode slots point their
 table rows at it, so the always-full-batch decode step has somewhere
 harmless to write. The allocator never hands it out.
 
-Layer-state layout (mirrors models/lm.init_decode_state):
-  attention layers   {"k","v"}: (num_blocks, block_size, KV, hd) pools,
-                     stacked layers carry a leading n_super axis;
+Layer-state layout (the layer tree of models/lm.init_decode_state):
+  attention layers   {"kv"}: one pool of token rows, (num_blocks *
+                     block_size, 2 * KV * hd) — row block * block_size +
+                     offset holds one token, K in the first KV * hd
+                     columns and V in the last (models/attention.py's
+                     row form); stacked layers carry a leading n_super
+                     axis, and the layer loop carries that array and
+                     updates it in place;
   recurrent layers   slot-indexed dense state, (num_slots, ...) per leaf —
                      O(num_slots), no paging needed.
 
 Quantized pools (`kv_dtype` "int8" / "fp8") shrink the per-token pool
-footprint 2-4x: attention layer dicts gain float32 "k_scale"/"v_scale"
-side-tables of shape (num_blocks, block_size, KV) — one max-abs scale per
-(token slot, kv head) over head_dim, the `optim/compression.py` quantizer
-shape localized per pool slot. Per-slot scales mean every write
-(prefill/decode/verify) quantizes independently — no lossy requantization
-on incremental decode — and copying a block's (q, scale) pair verbatim is
-an exact round-trip (the property the host spill tier relies on). The
-default "fp16" maps to cfg.act_dtype, keeping the unquantized path
-bit-identical to the pre-quantization layout.
+footprint 2-4x: attention layer dicts gain a float32 "kv_scale"
+side-table of shape (num_blocks * block_size, 2 * KV) — one max-abs scale
+per (token slot, kv head) over head_dim, K heads then V heads, the
+`optim/compression.py` quantizer shape localized per pool slot. Per-slot
+scales mean every write (prefill/decode/verify) quantizes independently —
+no lossy requantization on incremental decode — and copying a block's
+(q, scale) pair verbatim is an exact round-trip (the property the host
+spill tier relies on). The default "fp16" maps to cfg.act_dtype, keeping
+the unquantized path bit-identical to the pre-quantization layout.
 """
 from __future__ import annotations
 
@@ -42,7 +47,7 @@ from repro.serving.block_manager import NULL_BLOCK, BlockAllocator  # noqa: F401
 
 # block kinds whose KV lives in the paged pools (canonical set —
 # the engine's prefix-cache gate and copy_block both key off it)
-ATTN_KINDS = ("attn", "attn_local", "moe")
+ATTN_KINDS = lm.ATTN_KINDS
 
 # pool precisions: "fp16" is the activation dtype (bit-identical default);
 # the quantized modes carry per-slot scale side-tables.
@@ -69,26 +74,27 @@ def init_paged_state(cfg: ModelConfig, num_slots: int, num_blocks: int,
     """Paged decode-state pytree (same layer tree as init_decode_state)."""
     dt = cfg.act_dtype
     pool_dt = pool_dtype(cfg, kv_dtype)
+    rows = num_blocks * block_size
 
-    def layer_state(kind):
+    def layer_state(kind, lead=()):
         if kind in ATTN_KINDS:
-            shape = (num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
-            st = {"k": jnp.zeros(shape, pool_dt),
-                  "v": jnp.zeros(shape, pool_dt)}
+            st = {"kv": jnp.zeros(lead + (rows, _row_width(cfg)), pool_dt)}
             if quantized(kv_dtype):
-                st["k_scale"] = jnp.zeros(shape[:3], jnp.float32)
-                st["v_scale"] = jnp.zeros(shape[:3], jnp.float32)
+                st["kv_scale"] = jnp.zeros(lead + (rows, 2 * cfg.n_kv_heads),
+                                           jnp.float32)
             return st
-        return lm._init_block_state(cfg, kind, num_slots, 0, dt)
+        one = lm._init_block_state(cfg, kind, num_slots, 0, dt)
+        return jax.tree.map(lambda x: jnp.zeros(lead + x.shape, x.dtype),
+                            one)
 
-    state = {"prefix": [layer_state(k) for k in cfg.prefix_pattern]}
-    blocks = {}
-    for pi, kind in enumerate(cfg.block_pattern):
-        one = layer_state(kind)
-        blocks[f"p{pi}"] = jax.tree.map(
-            lambda x: jnp.zeros((cfg.n_super,) + x.shape, x.dtype), one)
-    state["blocks"] = blocks
-    return state
+    return {"prefix": [layer_state(k) for k in cfg.prefix_pattern],
+            "blocks": {f"p{pi}": layer_state(kind, (cfg.n_super,))
+                       for pi, kind in enumerate(cfg.block_pattern)}}
+
+
+def _row_width(cfg: ModelConfig) -> int:
+    """Columns of one token's pool row: its K and V heads side by side."""
+    return 2 * cfg.n_kv_heads * cfg.head_dim
 
 
 def _n_attn_layers(cfg: ModelConfig) -> int:
@@ -98,10 +104,10 @@ def _n_attn_layers(cfg: ModelConfig) -> int:
 
 def paged_bytes(cfg: ModelConfig, num_blocks: int, block_size: int,
                 kv_dtype: str = "fp16") -> int:
-    """Attention-cache bytes of the pool (the memory the paging bounds),
-    computed from the actual pool dtype plus the scale side-tables."""
-    per_tok = 2 * cfg.n_kv_heads * cfg.head_dim * pool_dtype(cfg,
-                                                             kv_dtype).itemsize
+    """Attention-cache bytes of the pool (the memory the paging bounds):
+    one row of 2 * KV * hd elements per token in the pool dtype, plus the
+    f32 scale row of a quantized pool."""
+    per_tok = _row_width(cfg) * pool_dtype(cfg, kv_dtype).itemsize
     if quantized(kv_dtype):
         per_tok += 2 * cfg.n_kv_heads * 4      # f32 scale per (slot, head)
     return _n_attn_layers(cfg) * num_blocks * block_size * per_tok
@@ -114,7 +120,24 @@ def block_bytes(cfg: ModelConfig, block_size: int,
     return paged_bytes(cfg, 1, block_size, kv_dtype)
 
 
-def copy_block(cfg: ModelConfig, state, src, dst):
+def _block_rows(ids, block_size: int):
+    """Pool rows of blocks `ids` (...): (..., block_size) int32."""
+    return ids[..., None] * block_size + jnp.arange(block_size,
+                                                    dtype=jnp.int32)
+
+
+def _at_rows(a, rows, stacked: bool):
+    """Index of `rows` in pool leaf `a`, in every layer of a stacked one.
+    The layer rides in the index, not in a slice window, so the scatter
+    and gather take the pool in its row-major layout (a window over the
+    layer axis makes XLA relayout the whole stack on TPU)."""
+    if not stacked:
+        return (rows,)
+    layers = jnp.arange(a.shape[0], dtype=jnp.int32)
+    return (layers.reshape((-1,) + (1,) * rows.ndim), rows[None])
+
+
+def copy_block(cfg: ModelConfig, state, src, dst, block_size: int):
     """Copy one physical block in every attention pool (src/dst are
     traced int32 block ids, so one jitted instance serves all copies).
     The copy-on-write primitive: a sequence that must write into a shared
@@ -122,13 +145,14 @@ def copy_block(cfg: ModelConfig, state, src, dst):
     Every pool leaf is copied — quantized pools carry their scale tables
     with the payload, so a COW copy round-trips exactly.
     Recurrent slot state is untouched — it is per-slot, never shared."""
+    src_rows = _block_rows(src, block_size)
+    dst_rows = _block_rows(dst, block_size)
 
     def copy_layer(kind, st, stacked):
         if kind not in ATTN_KINDS:
             return st
-        if stacked:
-            return {n: a.at[:, dst].set(a[:, src]) for n, a in st.items()}
-        return {n: a.at[dst].set(a[src]) for n, a in st.items()}
+        return {n: a.at[_at_rows(a, dst_rows, stacked)].set(
+            a[_at_rows(a, src_rows, stacked)]) for n, a in st.items()}
 
     new_prefix = [copy_layer(kind, st, False)
                   for kind, st in zip(cfg.prefix_pattern, state["prefix"])]
@@ -150,38 +174,23 @@ def load_prefill(cfg: ModelConfig, state, cache, slot, table_row,
     `slot` (int32 scalar) and `table_row` ((max_blocks,) int32) are traced,
     so one jitted instance serves every slot; the prompt length is static
     from `cache` leaf shapes. Attention K/V of prompt position p lands in
-    physical block table_row[p // block_size], offset p % block_size;
+    row table_row[p // block_size] * block_size + p % block_size;
     recurrent final states land at the slot index. Quantized pools
     quantize on landing, scattering (q, scale) per token slot.
     """
-    def attn_positions(n_tok):
-        pos = jnp.arange(n_tok)
-        return table_row[pos // block_size], pos % block_size
-
     def load_layer(kind, st, ca, stacked):
         if kind in ATTN_KINDS:
             # ca k/v: (B=1, P, KV, hd), stacked: (n_super, 1, P, KV, hd)
-            n_tok = ca["k"].shape[2] if stacked else ca["k"].shape[1]
-            blk, off = attn_positions(n_tok)
-            k, v = ca["k"], ca["v"]
-            if "k_scale" in st:
-                k, sk = attention.quantize_kv(k, st["k"].dtype)
-                v, sv = attention.quantize_kv(v, st["v"].dtype)
-            if stacked:
-                out = {"k": st["k"].at[:, blk, off].set(k[:, 0]),
-                       "v": st["v"].at[:, blk, off].set(v[:, 0])}
-                if "k_scale" in st:
-                    out["k_scale"] = st["k_scale"].at[:, blk, off].set(
-                        sk[:, 0])
-                    out["v_scale"] = st["v_scale"].at[:, blk, off].set(
-                        sv[:, 0])
-                return out
-            out = {"k": st["k"].at[blk, off].set(k[0]),
-                   "v": st["v"].at[blk, off].set(v[0])}
-            if "k_scale" in st:
-                out["k_scale"] = st["k_scale"].at[blk, off].set(sk[0])
-                out["v_scale"] = st["v_scale"].at[blk, off].set(sv[0])
-            return out
+            k, v = (ca["k"][:, 0], ca["v"][:, 0]) if stacked else (
+                ca["k"][0], ca["v"][0])
+            pos = jnp.arange(k.shape[-3])
+            rows = table_row[pos // block_size] * block_size + (
+                pos % block_size)
+            if not stacked:
+                return attention.write_kv(st, None, rows, k, v)
+            for layer in range(k.shape[0]):
+                st = attention.write_kv(st, layer, rows, k[layer], v[layer])
+            return st
         if stacked:
             return jax.tree.map(lambda s, c: s.at[:, slot].set(c[:, 0]),
                                 st, ca)
@@ -200,22 +209,25 @@ def load_prefill(cfg: ModelConfig, state, cache, slot, table_row,
 
 # ----------------------------------------------------------------------------
 # host-tier payload movement: gather blocks out of / scatter back into the
-# attention pools. Payload leaves all carry the block-width axis FIRST
-# (stacked layers are transposed to (W, n_super, bs, KV, hd)) so host-side
-# batching is a uniform axis-0 concatenate regardless of layer structure.
+# attention pools. Payload leaves all carry the block-width axis FIRST —
+# (W, block_size, C), stacked layers (W, n_super, block_size, C) — so
+# host-side batching is a uniform axis-0 concatenate regardless of layer
+# structure.
 # ----------------------------------------------------------------------------
 
-def gather_blocks(cfg: ModelConfig, state, ids):
+def gather_blocks(cfg: ModelConfig, state, ids, block_size: int):
     """Gather physical blocks `ids` ((W,) int32, traced) from every
     attention pool. Returns a pytree of (W, ...) leaves; recurrent layers
     contribute empty subtrees (their state is per-slot, never demoted)."""
+    rows = _block_rows(ids, block_size)                  # (W, bs)
 
     def g(kind, st, stacked):
         if kind not in ATTN_KINDS:
             return {}
         if stacked:
-            return {n: jnp.moveaxis(a[:, ids], 1, 0) for n, a in st.items()}
-        return {n: a[ids] for n, a in st.items()}
+            return {n: jnp.moveaxis(a[_at_rows(a, rows, True)], 1, 0)
+                    for n, a in st.items()}
+        return {n: a[rows] for n, a in st.items()}
 
     prefix = [g(kind, st, False)
               for kind, st in zip(cfg.prefix_pattern, state["prefix"])]
@@ -224,17 +236,20 @@ def gather_blocks(cfg: ModelConfig, state, ids):
     return {"prefix": prefix, "blocks": blocks}
 
 
-def scatter_blocks(cfg: ModelConfig, state, ids, payload):
+def scatter_blocks(cfg: ModelConfig, state, ids, payload, block_size: int):
     """Scatter a gather_blocks-shaped payload back into the pools at
     `ids`. Padded entries may target NULL_BLOCK (the null sink)."""
+    rows = _block_rows(ids, block_size)
 
     def s(kind, st, pa, stacked):
         if kind not in ATTN_KINDS:
             return st
         if stacked:
-            return {n: st[n].at[:, ids].set(
-                jnp.moveaxis(pa[n], 0, 1).astype(st[n].dtype)) for n in st}
-        return {n: st[n].at[ids].set(pa[n].astype(st[n].dtype)) for n in st}
+            return {n: a.at[_at_rows(a, rows, True)].set(
+                jnp.moveaxis(pa[n], 0, 1).astype(a.dtype))
+                for n, a in st.items()}
+        return {n: a.at[rows].set(pa[n].astype(a.dtype))
+                for n, a in st.items()}
 
     new_prefix = [s(kind, st, pa, False)
                   for kind, st, pa in zip(cfg.prefix_pattern,

@@ -42,11 +42,13 @@ position-keyed per request (fold_in(PRNGKey(seed), pos)); the runner
 holds no sampler state at all, which is what makes a request's stream
 independent of batch composition.
 
-All jitted state is donated, so pools update in place. The weights are
-an argument (not donated) of every program that reads them, never a
-closed-over constant, so each compiled variant shares the one device
-copy. The bucket-grid helpers live in `serving/bucketing.py` (shared
-with the bench's shape assertions).
+All jitted state is donated, so pools update in place: every program
+takes and returns the paged pools in the one row form of
+`models/attention.py`, and the layer loop carries them, so no dispatch
+relayouts or copies a pool. The weights are an argument (not donated)
+of every program that reads them, never a closed-over constant, so each
+compiled variant shares the one device copy. The bucket-grid helpers live
+in `serving/bucketing.py` (shared with the bench's shape assertions).
 """
 from __future__ import annotations
 
@@ -197,12 +199,13 @@ class ModelRunner:
         self.reset_stats()
 
         K = self.max_logprobs
+        bs = block_size
         self.params = params
 
         def _decode(params, state, tokens, positions, tables, temps, topks,
                     topps, seeds, do_sample, want_alt):
             logits, state = lm.decode_step_paged(params, cfg, state, tokens,
-                                                 positions, tables)
+                                                 positions, tables, bs)
             if do_sample:
                 tok, lp = sampling.sample_tokens(logits, positions, temps,
                                                  topks, topps, seeds)
@@ -217,7 +220,7 @@ class ModelRunner:
         def _verify(params, state, tokens, positions, counts, tables, temps,
                     topks, topps, seeds, do_sample, want_alt):
             logits, state, snaps = lm.decode_verify_paged(
-                params, cfg, state, tokens, positions, counts, tables)
+                params, cfg, state, tokens, positions, counts, tables, bs)
             if do_sample:
                 emit, accept, lp = sampling.verify_tokens(
                     logits, tokens, counts, positions, temps, topks, topps,
@@ -239,7 +242,7 @@ class ModelRunner:
         def _prefill(params, state, toks, lengths, cached, rows, slots,
                      resume):
             return lm.prefill_paged(params, cfg, state, toks, lengths,
-                                    cached, rows, slots, resume=resume)
+                                    cached, rows, slots, bs, resume=resume)
 
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,),
                                    static_argnums=(7,))
@@ -257,7 +260,7 @@ class ModelRunner:
         self._first_fn = jax.jit(_first, static_argnums=(6, 7))
 
         def _copy(state, src, dst):
-            return kv_cache.copy_block(cfg, state, src, dst)
+            return kv_cache.copy_block(cfg, state, src, dst, bs)
 
         self._copy_fn = jax.jit(_copy, donate_argnums=(0,))
 
@@ -271,12 +274,12 @@ class ModelRunner:
         self.promote_shapes: set = set()
 
         def _gather(state, ids):
-            return kv_cache.gather_blocks(cfg, state, ids)
+            return kv_cache.gather_blocks(cfg, state, ids, bs)
 
         self._gather_fn = jax.jit(_gather)
 
         def _upload(state, ids, payload):
-            return kv_cache.scatter_blocks(cfg, state, ids, payload)
+            return kv_cache.scatter_blocks(cfg, state, ids, payload, bs)
 
         self._upload_fn = jax.jit(_upload, donate_argnums=(0,))
 

@@ -6,9 +6,9 @@ Covers the layers the quantized/tiered cache spans:
     (q, scale) copy being a lossless round-trip;
   * kv_cache — pool dtype selection (fp16 keeps the activation dtype,
     int8/fp8 the narrow element types), dtype-aware paged_bytes/block_bytes,
-    scale side-tables in init_paged_state, copy_block carrying scales,
-    and gather_blocks/scatter_blocks round-tripping every pool leaf
-    exactly (the host-tier payload path);
+    row-form scale side-tables in init_paged_state, copy_block carrying
+    scales, and gather_blocks/scatter_blocks round-tripping every pool
+    leaf exactly (the host-tier payload path);
   * Pallas kernels — paged_attention and paged_prefill_attention with
     int8 pools + scale side-tables against their full-precision
     references (interpret mode);
@@ -119,27 +119,32 @@ def test_init_state_scales_and_copy_block_carries_them():
     nb, bs = 6, 4
     state = kv_cache.init_paged_state(CFG, 1, nb, bs, kv_dtype="int8")
     layers = [st for st in state["prefix"] if isinstance(st, dict)
-              and "k" in st]
+              and "kv" in st]
     stacked = [v for v in state["blocks"].values()
-               if isinstance(v, dict) and "k" in v]
-    assert all("k_scale" in st and "v_scale" in st
-               for st in layers + stacked)
+               if isinstance(v, dict) and "kv" in v]
+    assert all("kv_scale" in st for st in layers + stacked)
+    width = 2 * CFG.n_kv_heads * CFG.head_dim
     for v in stacked:
-        assert v["k_scale"].shape == (CFG.n_super, nb, bs, CFG.n_kv_heads)
-    # write recognizable payload + scale into block 2 of one stacked
-    # pool, then COW-copy to block 4: both must carry over exactly
+        assert v["kv"].shape == (CFG.n_super, nb * bs, width)
+        assert v["kv"].dtype == jnp.int8
+        assert v["kv_scale"].shape == (CFG.n_super, nb * bs,
+                                       2 * CFG.n_kv_heads)
+    # write recognizable payload + scale into block 2's rows of one
+    # stacked pool, then COW-copy to block 4: both must carry over exactly
     name = next(iter(state["blocks"]))
     leaf = state["blocks"][name]
-    k = leaf["k"].at[:, 2].set(7)
-    ks = leaf["k_scale"].at[:, 2].set(0.5)
-    state["blocks"][name] = dict(leaf, k=k, k_scale=ks)
-    out = kv_cache.copy_block(CFG, state, jnp.int32(2), jnp.int32(4))
+    b2, b4 = slice(2 * bs, 3 * bs), slice(4 * bs, 5 * bs)
+    kv = leaf["kv"].at[:, b2].set(7)
+    ks = leaf["kv_scale"].at[:, b2].set(0.5)
+    state["blocks"][name] = dict(leaf, kv=kv, kv_scale=ks)
+    out = kv_cache.copy_block(CFG, state, jnp.int32(2), jnp.int32(4), bs)
     got = out["blocks"][name]
-    np.testing.assert_array_equal(np.asarray(got["k"][:, 4]),
-                                  np.asarray(got["k"][:, 2]))
-    np.testing.assert_array_equal(np.asarray(got["k_scale"][:, 4]),
-                                  np.asarray(got["k_scale"][:, 2]))
-    assert np.all(np.asarray(got["k_scale"][:, 4]) == 0.5)
+    np.testing.assert_array_equal(np.asarray(got["kv"][:, b4]),
+                                  np.asarray(got["kv"][:, b2]))
+    np.testing.assert_array_equal(np.asarray(got["kv_scale"][:, b4]),
+                                  np.asarray(got["kv_scale"][:, b2]))
+    assert np.all(np.asarray(got["kv_scale"][:, b4]) == 0.5)
+    assert np.all(np.asarray(got["kv"][:, b4]) == 7)
 
 
 def test_gather_scatter_blocks_exact_roundtrip():
@@ -155,15 +160,16 @@ def test_gather_scatter_blocks_exact_roundtrip():
                    if jnp.issubdtype(x.dtype, jnp.floating) else x),
         state)
     ids = jnp.asarray([2, 5, 1], jnp.int32)
-    payload = kv_cache.gather_blocks(CFG, state, ids)
+    payload = kv_cache.gather_blocks(CFG, state, ids, bs)
     blank = kv_cache.init_paged_state(CFG, 1, nb, bs, kv_dtype="int8")
-    restored = kv_cache.scatter_blocks(CFG, blank, ids, payload)
+    restored = kv_cache.scatter_blocks(CFG, blank, ids, payload, bs)
     name = next(iter(state["blocks"]))
-    for field in ("k", "v", "k_scale", "v_scale"):
+    for field in ("kv", "kv_scale"):
         for b in (2, 5, 1):
+            rows = slice(b * bs, (b + 1) * bs)
             np.testing.assert_array_equal(
-                np.asarray(restored["blocks"][name][field][:, b]),
-                np.asarray(state["blocks"][name][field][:, b]))
+                np.asarray(restored["blocks"][name][field][:, rows]),
+                np.asarray(state["blocks"][name][field][:, rows]))
 
 
 # ---------------------------------------------------------------------------
@@ -211,13 +217,18 @@ def test_paged_prefill_kernel_quantized_matches_oracle():
     st_ = np.minimum(np.asarray(starts, np.int32), M * bs)
     ln = np.asarray(lengths, np.int32)
     pos = st_[:, None] + np.arange(Ls)[None, :].astype(np.int32)
-    qk, sk = quantize_kv(k_pool, jnp.int8)
-    qv, sv = quantize_kv(v_pool, jnp.int8)
-    cache = {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv}
+    # the oracle's row-form pool; the kernel reads its (P, bs, KV, hd)
+    # halves through reshape views
+    qkv, skv = quantize_kv(jnp.concatenate([k_pool, v_pool], axis=-2),
+                           jnp.int8)
+    cache = {"kv": qkv.reshape(P * bs, 2 * KV * hd),
+             "kv_scale": skv.reshape(P * bs, 2 * KV)}
+    qk, qv = qkv[..., :KV, :], qkv[..., KV:, :]
+    sk, sv = skv[..., :KV], skv[..., KV:]
     oracle = streamed_paged_attention(
         q, k_suf, v_suf, cache, jnp.asarray(bt), jnp.asarray(pos),
         jnp.asarray(st_), jnp.asarray(ln), scale=hd**-0.5,
-        attn_chunk=8, window=0)
+        attn_chunk=8, block_size=bs, window=0)
     got = paged_prefill_attention(
         q, k_suf, v_suf, qk, qv, jnp.asarray(bt), jnp.asarray(st_),
         jnp.asarray(ln), k_scale=sk, v_scale=sv, window=0, bq=8,

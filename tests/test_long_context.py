@@ -84,11 +84,15 @@ def _kernel_case(window, *, seed=0, N=3, Ls=16, H=4, KV=2, hd=16, bs=4,
     ln = np.asarray(lengths, np.int32)
     pos = st_[:, None] + np.arange(Ls)[None, :].astype(np.int32)
 
-    cache = {"k": k_pool, "v": v_pool}
+    # the oracle reads the row-form pool (K then V in each token's row);
+    # the kernel reads the same pool's halves through reshape views
+    rows = jnp.concatenate([k_pool, v_pool], axis=-2).reshape(P * bs, -1)
+    k_pool = rows[:, :KV * hd].reshape(P, bs, KV, hd)
+    v_pool = rows[:, KV * hd:].reshape(P, bs, KV, hd)
     oracle = streamed_paged_attention(
-        q, k_suf, v_suf, cache, jnp.asarray(bt), jnp.asarray(pos),
+        q, k_suf, v_suf, {"kv": rows}, jnp.asarray(bt), jnp.asarray(pos),
         jnp.asarray(st_), jnp.asarray(ln), scale=hd**-0.5,
-        attn_chunk=attn_chunk, window=window)
+        attn_chunk=attn_chunk, block_size=bs, window=window)
     got = paged_prefill_attention(
         q, k_suf, v_suf, k_pool, v_pool, jnp.asarray(bt),
         jnp.asarray(st_), jnp.asarray(ln), window=window, bq=8,

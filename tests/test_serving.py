@@ -88,20 +88,70 @@ def test_paged_model_path_matches_dense_decode():
     dense = {"k": hist_k * mask, "v": hist_v * mask}
     out_d, _ = attention.decode_attention_block(params, x, dense,
                                                 jnp.int32(pos), cfg)
-    # same history scattered into pools through a shuffled block table
+    # same history scattered into a row pool through a shuffled block
+    # table: block perm[b, j] holds tokens j*bs.. of slot b, one row each,
+    # K then V side by side
     perm = np.array([[3, 1, 4, 2], [7, 5, 8, 6]], np.int32)
     N = 9
-    kp = jnp.zeros((N, bs, cfg.n_kv_heads, cfg.head_dim))
-    vp = jnp.zeros((N, bs, cfg.n_kv_heads, cfg.head_dim))
+    width = cfg.n_kv_heads * cfg.head_dim
+    pool = jnp.zeros((N * bs, 2 * width))
     for b in range(B):
         for j in range(M):
-            kp = kp.at[perm[b, j]].set(dense["k"][b, j * bs:(j + 1) * bs])
-            vp = vp.at[perm[b, j]].set(dense["v"][b, j * bs:(j + 1) * bs])
+            hist = jnp.concatenate(
+                [dense["k"][b, j * bs:(j + 1) * bs].reshape(bs, width),
+                 dense["v"][b, j * bs:(j + 1) * bs].reshape(bs, width)], -1)
+            pool = pool.at[perm[b, j] * bs:(perm[b, j] + 1) * bs].set(hist)
     out_p, new_cache = attention.paged_decode_attention_block(
-        params, x, {"k": kp, "v": vp}, jnp.full((B,), pos, jnp.int32),
-        jnp.asarray(perm), cfg)
+        params, x, {"kv": pool}, jnp.full((B,), pos, jnp.int32),
+        jnp.asarray(perm), cfg, block_size=bs)
     np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_p),
                                atol=1e-5, rtol=1e-5)
+
+
+def test_prefill_and_decode_land_kv_in_token_rows():
+    """The row form: the K and V that prefill and decode write for the
+    token at position p land in pool row table[p // bs] * bs + p % bs of
+    every layer, K in the first KV * hd columns and V in the last, equal
+    to the dense forward's K/V; the runner's greedy tokens equal
+    generate()'s."""
+    from repro.serving.runner import ModelRunner, PrefillRow
+    cfg = get_config("smollm-135m").reduced()
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    bs, P = 4, 6
+    prompt = np.asarray(jax.random.randint(jax.random.fold_in(KEY, 40),
+                                           (P,), 0, cfg.vocab_size),
+                        np.int32)
+    table = np.array([3, 5, 0, 0], np.int32)
+    runner = ModelRunner(params, cfg, num_slots=2, block_size=bs,
+                         num_blocks=8, max_blocks_per_seq=4)
+    runner.write_table(0, table)
+    first, _, _ = runner.prefill([PrefillRow(tokens=prompt, cached_len=0,
+                                             slot=0, table_row=table)])
+    nxt, _, _ = runner.decode(np.array([first[0], 0], np.int32),
+                              np.array([P, 0], np.int32))
+    np.testing.assert_array_equal(
+        [first[0], nxt[0]], np.asarray(generate(params, cfg, prompt[None],
+                                                2))[0])
+
+    seq = np.append(prompt, first[0])
+    _, dense = lm.prefill(params, cfg, {"tokens": jnp.asarray(seq)[None]})
+    width = cfg.n_kv_heads * cfg.head_dim
+    pool = np.asarray(runner.state["blocks"]["p0"]["kv"])  # (L, 8*bs, 2w)
+    assert pool.shape == (cfg.n_super, 8 * bs, 2 * width)
+    k = np.asarray(dense["blocks"]["p0"]["k"])[:, 0].reshape(
+        cfg.n_super, P + 1, width)
+    v = np.asarray(dense["blocks"]["p0"]["v"])[:, 0].reshape(
+        cfg.n_super, P + 1, width)
+    for p in range(P + 1):                   # positions 0-5 prefill, 6 decode
+        row = table[p // bs] * bs + p % bs
+        np.testing.assert_allclose(pool[:, row, :width], k[:, p],
+                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(pool[:, row, width:], v[:, p],
+                                   atol=1e-5, rtol=1e-5)
+    # nothing else was written outside the null block's rows
+    written = {table[p // bs] * bs + p % bs for p in range(P + 1)}
+    others = [r for r in range(bs, 8 * bs) if r not in written]
+    assert not pool[:, others].any()
 
 
 # ----------------------------------------------------------------------------
@@ -388,18 +438,17 @@ def test_prefill_paged_matches_per_sequence_load_prefill(arch):
     last, fused = lm.prefill_paged(
         params, cfg, fused, toks, jnp.asarray(lens, jnp.int32),
         jnp.zeros(2, jnp.int32), jnp.asarray(tables),
-        jnp.arange(2, dtype=jnp.int32))
+        jnp.arange(2, dtype=jnp.int32), bs)
 
     np.testing.assert_allclose(np.asarray(last), np.stack(ref_last),
                                atol=1e-4, rtol=1e-4)
-    # compare every owned block / slot; block 0 is the pad sink (skip it)
-    for got, want in zip(jax.tree.leaves(fused), jax.tree.leaves(oracle)):
+    # compare every owned block / slot; block 0 is the pad sink (skip its
+    # rows)
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(fused),
+                                 jax.tree.leaves(oracle)):
         got, want = np.asarray(got), np.asarray(want)
-        if got.shape[-4:-2] == (6, bs) or got.shape[:2] == (6, bs):
-            np.testing.assert_allclose(got[..., 1:, :, :, :]
-                                       if got.ndim == 5 else got[1:],
-                                       want[..., 1:, :, :, :]
-                                       if want.ndim == 5 else want[1:],
+        if path[-1] == jax.tree_util.DictKey("kv"):   # (.., 6 * bs, C)
+            np.testing.assert_allclose(got[..., bs:, :], want[..., bs:, :],
                                        atol=1e-4, rtol=1e-4)
         else:                         # recurrent slot state
             np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)

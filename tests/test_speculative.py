@@ -126,7 +126,7 @@ def test_decode_verify_matches_sequential_decode(arch):
                                 jnp.asarray(lens, jnp.int32),
                                 jnp.zeros(2, jnp.int32),
                                 jnp.asarray(tables),
-                                jnp.arange(2, dtype=jnp.int32))
+                                jnp.arange(2, dtype=jnp.int32), bs)
 
     rng = np.random.default_rng(0)
     chains = rng.integers(0, cfg.vocab_size, (2, 4)).astype(np.int32)
@@ -137,7 +137,7 @@ def test_decode_verify_matches_sequential_decode(arch):
         lg, seq_state = lm.decode_step_paged(
             params, cfg, seq_state, jnp.asarray(chains[:, t]),
             jnp.asarray([lens[0] + t, lens[1] + t], jnp.int32),
-            jnp.asarray(tables))
+            jnp.asarray(tables), bs)
         ref_logits[0].append(np.asarray(lg[0]))
         if t < 3:
             ref_logits[1].append(np.asarray(lg[1]))
@@ -145,7 +145,7 @@ def test_decode_verify_matches_sequential_decode(arch):
     logits, vstate, snaps = lm.decode_verify_paged(
         params, cfg, state, jnp.asarray(chains),
         jnp.asarray(lens, jnp.int32), jnp.asarray(counts),
-        jnp.asarray(tables))
+        jnp.asarray(tables), bs)
     for b in range(2):
         for t in range(int(counts[b])):
             np.testing.assert_allclose(np.asarray(logits[b, t]),
@@ -161,19 +161,20 @@ def test_decode_verify_matches_sequential_decode(arch):
         _, rs = lm.decode_step_paged(
             params, cfg, rs, jnp.asarray([chains[0, t], chains[1, 0]]),
             jnp.asarray([lens[0] + t, lens[1]], jnp.int32),
-            jnp.asarray(tables))
+            jnp.asarray(tables), bs)
     rs1 = state
     _, rs1 = lm.decode_step_paged(
         params, cfg, rs1, jnp.asarray([chains[0, 0], chains[1, 0]]),
-        jnp.asarray([lens[0], lens[1]], jnp.int32), jnp.asarray(tables))
+        jnp.asarray([lens[0], lens[1]], jnp.int32), jnp.asarray(tables),
+        bs)
     nxt = jnp.asarray([11, 12], jnp.int32)
     pos = jnp.asarray([lens[0] + 3, lens[1] + 1], jnp.int32)
     lg_commit, _ = lm.decode_step_paged(params, cfg, cstate, nxt, pos,
-                                        jnp.asarray(tables))
+                                        jnp.asarray(tables), bs)
     lg_ref0, _ = lm.decode_step_paged(params, cfg, rs, nxt, pos,
-                                      jnp.asarray(tables))
+                                      jnp.asarray(tables), bs)
     lg_ref1, _ = lm.decode_step_paged(params, cfg, rs1, nxt, pos,
-                                      jnp.asarray(tables))
+                                      jnp.asarray(tables), bs)
     np.testing.assert_allclose(np.asarray(lg_commit[0]),
                                np.asarray(lg_ref0[0]),
                                atol=2e-4, rtol=2e-4)

@@ -11,13 +11,19 @@ published widths:
     flash-attention kernel;
   * the paged decode step exactly as `serving/runner.py` jits it
     (`lm.decode_step_paged` plus greedy sampling, weights passed as
-    shapes), with the depth cut to 2 layers so it compiles quickly.
+    shapes), with the depth cut to 2 layers so it compiles quickly;
+  * every runner program that takes the paged KV pool (decode greedy and
+    sampled, one prefill bucket, verify, copy-on-write and host-tier
+    upload) at 64 lanes and 4097 blocks of 16, depth cut to 4 layers:
+    none may copy, slice or rebuild the pool, and each must take and
+    return it in the same row-major format.
 
 Nothing runs, so these say nothing about results or times. The topology
 is described inside a module fixture, never at import: only one process
 may load the TPU library at a time.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +35,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.paged_prefill import paged_prefill_attention
 from repro.models import lm
+from repro.serving import kv_cache
 from repro.serving.runner import ModelRunner
 
 SMOLLM = get_config("smollm-135m")
@@ -151,3 +158,110 @@ def test_runner_decode_step_compiles_for_v5e(one_chip, no_persistent_cache):
     # the weights arrive as arguments: none of them is compiled in
     assert mem.argument_size_in_bytes >= weight_bytes
     assert mem.generated_code_size_in_bytes < weight_bytes
+
+
+# ----------------------------------------------------------------------------
+# The paged KV pool stays in place: no runner program moves it
+# ----------------------------------------------------------------------------
+
+LANES, MB, NB = 64, 64, 4097        # 64 lanes of 1024 tokens, 4097 blocks
+MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+
+
+def _is_pool(shape):
+    """A pool array, a layer of one, or the old (.., blocks, bs, KV, hd)
+    form: the shape holds the pool's row count or its block count."""
+    return NB * BS in shape or NB in shape
+
+
+def _pool_moves(hlo):
+    """Instructions of the non-fused computations (entry, loop bodies)
+    that copy, slice or update-slice an array of the pool's size."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo))
+    moves, comp = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        ins = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                       r"([\w\-]+)\(", line)
+        if comp in fused or not ins or ins.group(3) not in MOVES:
+            continue
+        dims = tuple(int(d) for d in ins.group(2).split(",") if d)
+        if _is_pool(dims):
+            moves.append(f"{comp}: {ins.group(1)} {ins.group(3)}{list(dims)}")
+    return moves
+
+
+def _pool_formats(formats, state):
+    """The formats of the pool leaves of a state-shaped format tree."""
+    leaves = jax.tree.leaves(state)
+    fmts = jax.tree.leaves(formats, is_leaf=lambda x: x is None)
+    assert len(fmts) == len(leaves)
+    return [f for f, x in zip(fmts, leaves) if _is_pool(x.shape)]
+
+
+@pytest.fixture(scope="module")
+def pool_programs(one_chip, no_persistent_cache):
+    """Lowers every runner program that takes the pool, at smollm-135m
+    widths and 4 layers. The runner is built over a 2-block pool (no
+    1.5 GB pool is allocated here); its programs are lowered for the
+    full 4097-block state as shapes on the chip."""
+    cfg = dataclasses.replace(SMOLLM, n_layers=4)
+    param_shapes = jax.eval_shape(
+        lambda: lm.init_params(jax.random.PRNGKey(0), cfg))
+    runner = ModelRunner(param_shapes, cfg, num_slots=LANES, block_size=BS,
+                         num_blocks=2, max_blocks_per_seq=MB, speculate=3)
+
+    def on_chip(x):
+        return _sds(one_chip, x.shape, x.dtype)
+
+    P = jax.tree.map(on_chip, param_shapes)
+    S = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: kv_cache.init_paged_state(cfg, LANES, NB, BS)))
+    lanes = _sds(one_chip, (LANES,), jnp.int32)
+    temps = _sds(one_chip, (LANES,), jnp.float32)
+    tables = _sds(one_chip, (LANES, MB), jnp.int32)
+    samp = (temps, lanes, temps, lanes)
+    rows = _sds(one_chip, (4,), jnp.int32)
+    ids = _sds(one_chip, (8,), jnp.int32)
+    one = _sds(one_chip, (), jnp.int32)
+    payload = jax.tree.map(on_chip, jax.eval_shape(runner._gather_fn, S,
+                                                   ids))
+    # program -> (lowering thunk, index of the state in args, in outputs)
+    return S, {
+        "decode": (lambda: runner._decode_fn.lower(
+            P, S, lanes, lanes, tables, *samp, False, False), 1, 3),
+        "decode_sampled": (lambda: runner._decode_fn.lower(
+            P, S, lanes, lanes, tables, *samp, True, True), 1, 3),
+        "prefill": (lambda: runner._prefill_fn.lower(
+            P, S, _sds(one_chip, (4, 256), jnp.int32), rows, rows,
+            _sds(one_chip, (4, MB), jnp.int32), rows, False), 1, 1),
+        "verify": (lambda: runner._verify_fn.lower(
+            P, S, _sds(one_chip, (LANES, 4), jnp.int32), lanes, lanes,
+            tables, *samp, False, False), 1, 4),
+        "copy_block": (lambda: runner._copy_fn.lower(S, one, one), 0, None),
+        "upload": (lambda: runner._upload_fn.lower(S, ids, payload), 0,
+                   None),
+    }
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_sampled", "prefill",
+                                     "verify", "copy_block", "upload"])
+def test_runner_programs_keep_the_pool_in_place(pool_programs, program):
+    """No non-fused computation of the program copies, slices or rebuilds
+    an array of the pool's size (the layer loop carries the pool and
+    scatters its new rows in place), and the program takes and returns
+    the pool in one row-major format, so no call relayouts it."""
+    state, programs = pool_programs
+    lower, arg, out = programs[program]
+    compiled = lower().compile()
+    assert _pool_moves(compiled.as_text()) == []
+    args, _ = compiled.input_formats
+    outs = compiled.output_formats
+    fmt_in = _pool_formats(args[arg], state)
+    fmt_out = _pool_formats(outs if out is None else outs[out], state)
+    assert fmt_in and fmt_in == fmt_out
+    for f in fmt_in:
+        assert f.layout.major_to_minor == (0, 1, 2)
