@@ -1,6 +1,6 @@
 """What every part of the chip benchmark shares: where its files are, how a
-name in `BENCHMARK.json` finds its file, the compile counter, the device
-record and the result line.
+name in `BENCHMARK.json` or a configuration finds its file, the compile
+counter, the device record and the result line.
 
 Nothing here imports the program under test (`src/repro`); the drivers do.
 """
@@ -14,6 +14,8 @@ import sys
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]            # the checkout: BENCHMARK.json, src/
+ARCH_DIR = HERE / "arch"
+_ARCHS: dict = {}
 
 
 def load_json(path) -> dict:
@@ -69,6 +71,27 @@ def load_reader(metric: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def arch(spec: dict):
+    """The architecture module that a configuration's `model` block names
+    with `"arch"` (default "dense"): `arch/<name>.py`. It gives the weight
+    tree `shapes(spec)` with each leaf's init std `scale(path, shape)`, the
+    plain float32 forward `logits(w, spec, tokens, quant, remat)`, and the
+    work counts `param_count`, `matmul_params`, `kv_bytes_per_token`,
+    `prefill_flops`, `decode_flops`, `decode_bytes` and
+    `train_flops_per_token` (see `arch/dense.py`)."""
+    name = spec.get("arch", "dense")
+    path = ARCH_DIR / f"{name}.py"
+    if path not in _ARCHS:
+        if not path.is_file():
+            raise SystemExit(f"no architecture {name!r}: {path} not found")
+        mod_spec = importlib.util.spec_from_file_location(
+            f"chipbench_arch_{name}", path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        mod_spec.loader.exec_module(mod)
+        _ARCHS[path] = mod
+    return _ARCHS[path]
 
 
 def device_record(devices, trace_summary=None) -> dict:

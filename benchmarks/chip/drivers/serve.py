@@ -38,8 +38,12 @@ PROGRAMS = {"decode": "jit__decode", "prefill": "jit__prefill",
 
 
 def model_config(spec: dict):
+    """The program's `ModelConfig` for a configuration's `model` block; the
+    block's `arch` names the harness's own architecture module and is not
+    the program's."""
     from repro.configs.base import ModelConfig
     kw = dict(spec)
+    kw.pop("arch", None)
     kw["block_pattern"] = tuple(kw.get("block_pattern", ("attn",)))
     return ModelConfig(**kw)
 

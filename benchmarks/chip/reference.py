@@ -1,20 +1,9 @@
-"""The plain reference: a float32 forward pass of a dense decoder whose
-every layer is attention + SwiGLU, written from the model's equations and
-importing nothing of the program.
-
-    x = embed[tokens]
-    per layer:  h = rmsnorm(x) * (1 + g1)
-                q, k, v = h Wq, h Wk, h Wv      (GQA: H query, KV key heads)
-                q, k = rope(q), rope(k)         (split-half rotation, full
-                                                 head width, base theta)
-                x = x + softmax(q k^T / sqrt(hd), causal) v Wo
-                h = rmsnorm(x) * (1 + g2)
-                x = x + (silu(h Wg) * (h Wu)) Wd
-    logits = (rmsnorm(x) * (1 + gf)) E^T        (tied) or ... Whead
-
-Every matrix product runs at `Precision.HIGHEST`, so a TPU computes it in
-float32 and not in one bfloat16 pass. Attention is taken in blocks of
-query rows so that long contexts fit.
+"""The plain reference: a float32 forward pass written from the model's
+equations, importing nothing of the program. The layer equations are the
+architecture's (`arch/<name>.py`, found by `common.arch`, built on the
+float32 matrix product and blocked attention of `ops.py`); this file holds
+the readings a serving check compares. The architecture reads the weights
+in float32 (`ops.f32`), whatever type they are stored in.
 
 `quant="fp8"` is the control: the same pass with both operands of every
 matrix product rounded to float8 (e4m3, one max-abs scale per tensor), the
@@ -27,93 +16,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-HI = jax.lax.Precision.HIGHEST
-Q_BLOCK = 512
-
-
-def _qdq(x, quant):
-    if quant is None:
-        return x
-    if quant != "fp8":
-        raise ValueError(quant)
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
-    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-def _mm(a, b, quant):
-    return jnp.matmul(_qdq(a, quant), _qdq(b, quant), precision=HI)
-
-
-def _rms(x, g, eps):
-    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return x * (1.0 + g)
-
-
-def _rope(x, pos, theta):
-    hd = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = pos[:, None, None].astype(jnp.float32) * freqs
-    c, s = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-
-
-def _attention(q, k, v, quant):
-    """q (S, KV, G, hd), k/v (S, KV, hd) -> (S, KV*G*hd), causal."""
-    S, KV, G, hd = q.shape
-    kpos = jnp.arange(S)
-    kq, vq = _qdq(k, quant), _qdq(v, quant)
-    outs = []
-    for lo in range(0, S, Q_BLOCK):
-        qb = q[lo:lo + Q_BLOCK]
-        n = qb.shape[0]
-        s = jnp.einsum("qkgh,skh->kgqs", _qdq(qb, quant), kq,
-                       precision=HI) * hd ** -0.5
-        mask = kpos[None, :] <= (lo + jnp.arange(n))[:, None]
-        s = jnp.where(mask, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("kgqs,skh->qkgh", _qdq(p, quant), vq, precision=HI)
-        outs.append(o.reshape(n, KV * G * hd))
-    return jnp.concatenate(outs, axis=0)
-
-
-def _layer(x, lw, spec, pos, quant):
-    S = x.shape[0]
-    H, KV, hd = spec["n_heads"], spec["n_kv_heads"], spec["head_dim"]
-    eps, theta = spec["norm_eps"], spec["rope_theta"]
-    h = _rms(x, lw["norm1"], eps)
-    q = _mm(h, lw["attn"]["wq"], quant).reshape(S, H, hd)
-    k = _mm(h, lw["attn"]["wk"], quant).reshape(S, KV, hd)
-    v = _mm(h, lw["attn"]["wv"], quant).reshape(S, KV, hd)
-    q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-    o = _attention(q.reshape(S, KV, H // KV, hd), k, v, quant)
-    x = x + _mm(o, lw["attn"]["wo"], quant)
-    h = _rms(x, lw["norm2"], eps)
-    m = lw["mlp"]
-    act = jax.nn.silu(_mm(h, m["w_gate"], quant)) * _mm(h, m["w_up"], quant)
-    return x + _mm(act, m["w_down"], quant)
-
-
-def hidden(w, spec, tokens, quant=None, remat=False):
-    """Final normed hidden states (S, D) of one sequence. `remat` keeps
-    only each layer's input for a backward pass (same arithmetic)."""
-    pos = jnp.arange(tokens.shape[0])
-    x = w["embed"][tokens].astype(jnp.float32)
-
-    def body(x, lw):
-        return _layer(x, lw, spec, pos, quant), None
-
-    if remat:
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, w["blocks"]["p0"])
-    return _rms(x, w["final_norm"], spec["norm_eps"])
+import common
 
 
 def logits(w, spec, tokens, quant=None, remat=False):
     """(S, V) float32 logits of one sequence of token ids."""
-    h = hidden(w, spec, tokens, quant, remat)
-    table = w["embed"].T if spec.get("tie_embeddings") else w["head"]
-    return _mm(h, table, quant)
+    return common.arch(spec).logits(w, spec, tokens, quant, remat)
 
 
 @functools.partial(jax.jit, static_argnames=("spec_items", "control"))

@@ -33,11 +33,29 @@ CLOSED = {"loop": "closed", "clients": 3, "per_client": 400, "block": 3,
 ENGINE = {"num_slots": 4, "block_size": 8, "max_seq_len": 72,
           "max_logprobs": 1}
 
+# smollm2-code's shape at a test's size: multi-head (KV = H), weights
+# stored in bfloat16, a closed loop replaying one order of sizes whose
+# prompts take two to four prefill chunks, and a pool smaller than lanes x
+# max_seq_len, so that admission sometimes waits for blocks
+MHA = dict(TINY, n_heads=4, n_kv_heads=4, head_dim=20,
+           param_dtype="bfloat16")
+
+LONG_CLOSED = {"loop": "closed", "clients": 4, "per_client": 400,
+               "block": 8, "sizes_seed": 0, "prime_steps": 12,
+               "prompt": {"median": 64, "sigma": 0.35, "min": 36,
+                          "max": 100},
+               "output": {"median": 6, "sigma": 0.3, "min": 4, "max": 8},
+               "sampling": {"temperature": 0.7, "top_p": 0.95},
+               "greedy_every": 2, "logprobs": 1}
+
+LONG_ENGINE = {"num_slots": 4, "block_size": 8, "max_seq_len": 112,
+               "num_blocks": 41, "max_logprobs": 1, "prefill_chunk": 32}
+
 
 def serve_ctx(mix=OPEN, engine=ENGINE, seed=7, seconds=1.5, trace=False,
-              tie=True, limits=None):
+              limits=None, model=None):
     import common
-    spec = dict(TINY, tie_embeddings=tie)
+    spec = dict(model or TINY)
     cell = {"driver": "serve", "engine": dict(engine),
             "check": {"greedy": 3, "sampled": 2,
                       "limits": limits or {"gap_max": 1e-3,

@@ -58,6 +58,43 @@ def test_sound_run_is_correct(loop):
     json.dumps(out)
 
 
+def test_long_closed_loop_prefills_in_chunks(monkeypatch):
+    """smollm2-code's shape: multi-head, weights in bfloat16, a closed loop
+    whose prompts run past `prefill_chunk`, on a pool that cannot hold every
+    lane at its longest. Warm-up covers every chunked-prefill variant, so
+    nothing compiles after it, in the priming steps or the window, and what
+    was served passes the reference check at tight limits."""
+    import drivers.serve as serve
+    from repro.serving.runner import ModelRunner
+    ctx = cells.serve_ctx(mix=cells.LONG_CLOSED, engine=cells.LONG_ENGINE,
+                          model=cells.MHA, seed=2**33 + 3)
+    seen = {"resumed": 0}
+    inner_prefill, inner_warm, inner_check = (ModelRunner.prefill,
+                                              serve.warm_up, serve.check)
+
+    def prefill(self, rows, resume=False, **kw):
+        seen["resumed"] += bool(resume)
+        return inner_prefill(self, rows, resume=resume, **kw)
+
+    def warm_up(*a):
+        n = inner_warm(*a)
+        seen["warm"] = ctx["log"].compiles
+        return n
+
+    def check(*a, **k):
+        seen["served"] = ctx["log"].compiles
+        return inner_check(*a, **k)
+
+    monkeypatch.setattr(ModelRunner, "prefill", prefill)
+    monkeypatch.setattr(serve, "warm_up", warm_up)
+    monkeypatch.setattr(serve, "check", check)
+    out = run.run_cell(ctx)
+    assert out["correct"], out["checks"]
+    assert out["failed"] == 0 and out["attempted"] > 5
+    assert seen["resumed"] > 0
+    assert seen["served"] == seen["warm"]
+
+
 def test_traced_counters_without_a_device_trace():
     """lane_occupancy and kv_pool_use come from the program's counters, so
     they read on the CPU too; the trace's metrics stay silent there."""
@@ -116,22 +153,32 @@ def _prefill_state_unchanged(monkeypatch):
     monkeypatch.setattr(lm, "prefill_paged", prefill_paged)
 
 
+SHAPES = {
+    "chat": lambda seed: cells.serve_ctx(seed=seed),
+    "code": lambda seed: cells.serve_ctx(
+        mix=cells.LONG_CLOSED, engine=cells.LONG_ENGINE,
+        model=cells.MHA, seed=seed),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
 @pytest.mark.parametrize("fault", ["altered_token", "decode_state_unchanged",
                                    "prefill_state_unchanged"])
-def test_broken_timed_path_is_not_correct(fault, monkeypatch):
+def test_broken_timed_path_is_not_correct(fault, shape, monkeypatch):
     {"altered_token": _altered_token,
      "decode_state_unchanged": _state_unchanged,
      "prefill_state_unchanged": _prefill_state_unchanged}[fault](monkeypatch)
-    out = run.run_cell(cells.serve_ctx(seed=11))
+    out = run.run_cell(SHAPES[shape](11))
     assert not out["correct"]
     assert max(c["value"] for c in out["checks"].values()) > 1e-2
 
 
-def test_control_is_not_correct():
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_control_is_not_correct(shape):
     """The control, the fp8 reference put in the program's place, fails the
     limits that a sound run of the same tiny cell passes."""
     import drivers.serve as serve
-    ctx = cells.serve_ctx(seed=5)
+    ctx = SHAPES[shape](5)
     b = serve.build(ctx, 5)
     reqs = serve.cell_requests(ctx, 5, ctx["seconds"])
     st = serve.window(b["engine"], reqs, ctx["traffic"], ctx["seconds"],

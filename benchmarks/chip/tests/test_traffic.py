@@ -3,6 +3,7 @@ the same requests, and every seed offers the same work."""
 import collections
 
 import numpy as np
+import pytest
 
 import cells
 import common
@@ -23,8 +24,10 @@ def test_same_seed_same_requests():
         assert _key(a) != _key(c)
 
 
-def test_every_seed_offers_the_same_work():
-    mix = common.traffic("chat-poisson")
+@pytest.mark.parametrize("name", ["chat-poisson", "code-closed"])
+def test_every_seed_offers_the_same_work(name):
+    """The same multiset of sizes and gaps from every seed."""
+    mix = common.traffic(name)
     n = 4 * mix["block"]
     sizes = []
     for seed in (1, 99, 2**32 + 17):
@@ -60,3 +63,23 @@ def test_open_loop_rate_and_counts():
     n = traffic.count_for(closed, 10.0)
     assert n % closed["block"] == 0
     assert n == closed["clients"] * (closed["per_client"] + 2)
+
+
+def test_sizes_seed_replays_one_order():
+    """With `sizes_seed` every run seed gets the same sizes in the same
+    order, and other token ids and sampling streams; a mix without the key
+    draws what it drew before the key existed."""
+    mix = common.traffic("code-closed")
+    runs = [traffic.requests(mix, s, 3 * mix["block"], 49152)
+            for s in (5, 2**33 + 1)]
+    for key in ("max_new", "temperature", "top_p"):
+        assert [r[key] for r in runs[0]] == [r[key] for r in runs[1]]
+    assert [len(r["prompt"]) for r in runs[0]] == \
+        [len(r["prompt"]) for r in runs[1]]
+    assert _key(runs[0]) != _key(runs[1])
+    plain = dict(mix)
+    del plain["sizes_seed"]
+    rng = np.random.default_rng(9)
+    p_q = traffic._lognormal_quantiles(plain["prompt"], mix["block"])
+    first = traffic.requests(plain, 9, mix["block"], 49152)
+    assert [len(r["prompt"]) for r in first] == list(rng.permutation(p_q))

@@ -10,7 +10,12 @@ stratified. Each consecutive block of `block` requests takes the quantiles
 (i + 0.5) / block of the distributions, and the seed only shuffles them
 within the block and draws the token ids. So every seed offers the same
 work, in another order and with other content, and any whole number of
-blocks holds the same multiset of sizes.
+blocks holds the same multiset of sizes. A mix with `sizes_seed` replays
+one sequence of sizes: their order (and an open loop's gaps) is drawn from
+that fixed seed, so every run serves the same sizes in the same order, and
+the run's seed draws the token ids and sampling streams alone. A closed
+loop's window holds a few tens of requests, so a seed that reordered the
+sizes would change the window's work.
 
 Keys of a serving mix:
   loop        "open" (arrivals on a schedule) or "closed" (each client
@@ -20,6 +25,7 @@ Keys of a serving mix:
   per_client  closed loop: requests drawn per client per 10 s of window
   prime_steps closed loop: engine steps the clients run before the window
   block       stratification block (requests)
+  sizes_seed  optional: a fixed seed for the sizes' order (replay)
   prompt      {"median", "sigma", "min", "max"}: prompt tokens
   output      {"median", "sigma", "min", "max"}: new tokens per request
   sampling    {"temperature", "top_p"} for sampled requests
@@ -54,6 +60,8 @@ def requests(mix: dict, seed: int, n: int, vocab_size: int) -> list:
     temperature, top_p, seed (the request's own sampling stream), logprobs.
     The same (mix, seed, n) always gives the same requests."""
     rng = np.random.default_rng(seed)
+    order = (np.random.default_rng(int(mix["sizes_seed"]))
+             if "sizes_seed" in mix else rng)
     block = int(mix["block"])
     n_blocks = -(-n // block)
     plens, olens, gaps = [], [], []
@@ -62,10 +70,10 @@ def requests(mix: dict, seed: int, n: int, vocab_size: int) -> list:
     g_q = (_exponential_quantiles(mix["rate"], block)
            if mix["loop"] == "open" else None)
     for _ in range(n_blocks):
-        plens.append(rng.permutation(p_q))
-        olens.append(rng.permutation(o_q))
+        plens.append(order.permutation(p_q))
+        olens.append(order.permutation(o_q))
         if g_q is not None:
-            gaps.append(rng.permutation(g_q))
+            gaps.append(order.permutation(g_q))
     plens = np.concatenate(plens)[:n]
     olens = np.concatenate(olens)[:n]
     arrivals = (np.cumsum(np.concatenate(gaps)[:n]) if gaps

@@ -2,15 +2,18 @@
 reference and the program read the same numbers and the reference takes
 nothing the program made.
 
-The layout is the one `models/lm.py` takes for a dense model whose every
-layer is attention + SwiGLU (`block_pattern == ("attn",)`): layers stacked
-on a leading axis under blocks/p0, norms as (1 + scale). It is built in one
-jitted call, on the device, in float32 (the program's master copy; it casts
-to its compute dtype at each call).
+The tree and each leaf's init scale are the architecture's
+(`arch/<name>.py`, found by `common.arch`). Every leaf is a seeded normal
+times that scale, the i-th leaf in flattening order drawn from
+fold_in(key, i). It is built in one jitted call, on the device, drawn in
+float32 and stored in the spec's `param_dtype` (default float32, the
+program's master copy; it casts to its compute dtype at each call).
 """
 from __future__ import annotations
 
 import numpy as np
+
+import common
 
 
 def key_for(seed: int):
@@ -21,32 +24,8 @@ def key_for(seed: int):
 
 
 def shapes(spec: dict) -> dict:
-    L, D, F, V = (spec["n_layers"], spec["d_model"], spec["d_ff"],
-                  spec["vocab_size"])
-    H, KV, hd = spec["n_heads"], spec["n_kv_heads"], spec["head_dim"]
-    tree = {
-        "embed": (V, D),
-        "prefix": [],
-        "blocks": {"p0": {
-            "norm1": (L, D), "norm2": (L, D),
-            "attn": {"wq": (L, D, H * hd), "wk": (L, D, KV * hd),
-                     "wv": (L, D, KV * hd), "wo": (L, H * hd, D)},
-            "mlp": {"w_gate": (L, D, F), "w_up": (L, D, F),
-                    "w_down": (L, F, D)},
-        }},
-        "final_norm": (D,),
-    }
-    if not spec.get("tie_embeddings", False):
-        tree["head"] = (D, V)
-    return tree
-
-
-def _scale(path: str, shape: tuple) -> float:
-    if path == "embed":
-        return 0.02
-    if "norm" in path:
-        return 0.1                 # (1 + scale): gains of 1 +- 0.1
-    return shape[-2] ** -0.5       # fan-in of a matrix
+    """The weight tree of `spec`'s architecture, leaf shapes as tuples."""
+    return common.arch(spec).shapes(spec)
 
 
 def make(spec: dict, seed: int, sharding=None):
@@ -55,20 +34,23 @@ def make(spec: dict, seed: int, sharding=None):
     import jax
     import jax.numpy as jnp
 
-    tree = shapes(spec)
+    arch = common.arch(spec)
+    tree = arch.shapes(spec)
     leaves, treedef = jax.tree.flatten(
         tree, is_leaf=lambda x: isinstance(x, tuple))
     paths = [jax.tree_util.keystr(p) for p, _ in
              jax.tree_util.tree_flatten_with_path(
                  tree, is_leaf=lambda x: isinstance(x, tuple))[0]]
-    scales = [_scale(p.replace("'", "").strip("[]"), s)
+    scales = [arch.scale(p.replace("'", "").strip("[]"), s)
               for p, s in zip(paths, leaves)]
+
+    dtype = jnp.dtype(spec.get("param_dtype", "float32"))
 
     def build(key):
         out = []
         for i, (shape, sc) in enumerate(zip(leaves, scales)):
-            out.append(jax.random.normal(jax.random.fold_in(key, i), shape,
-                                         jnp.float32) * sc)
+            out.append((jax.random.normal(jax.random.fold_in(key, i), shape,
+                                          jnp.float32) * sc).astype(dtype))
         return jax.tree.unflatten(treedef, out)
 
     return jax.jit(build, out_shardings=sharding)(key_for(seed))
