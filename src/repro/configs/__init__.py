@@ -16,6 +16,7 @@ ARCHS = {
     "recurrentgemma-2b": "recurrentgemma_2b",
     "paligemma-3b": "paligemma_3b",
     "musicgen-medium": "musicgen_medium",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "paper-lsq": "paper_lsq",
 }
 

@@ -13,6 +13,10 @@ from typing import Tuple
 import jax.numpy as jnp
 
 
+# block kinds whose feed-forward is a routed mixture of experts
+MOE_KINDS = ("moe", "mla_moe")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -24,13 +28,31 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     head_dim: int = 0
-    # block structure: tuple of 'attn' | 'moe' | 'rwkv' | 'rec' | 'attn_local'
+    # block structure: tuple of 'attn' | 'moe' | 'rwkv' | 'rec' |
+    # 'attn_local' | 'mla' | 'mla_moe'
     block_pattern: Tuple[str, ...] = ("attn",)
+    # leading dense layers before the pattern repeats: each is the dense
+    # form of the pattern's attention ('mla' for MLA models, else 'attn')
+    # with a d_ff-wide MLP (DeepSeek's first_k_dense_replace)
+    n_dense_layers: int = 0
     mlp_kind: str = "swiglu"
     # MoE
     n_experts: int = 0
     experts_per_token: int = 0
     capacity_factor: float = 1.25
+    # route every token to all its experts, whatever else is in the
+    # dispatch (moe.moe_dropless), not through the capacity dispatch;
+    # ServingEngine serves every MoE dropless
+    dropless: bool = False
+    moe_d_ff: int = 0              # routed-expert width (0 = d_ff)
+    n_shared_experts: int = 0      # always-on experts of width moe_d_ff
+    # multi-head latent attention (MLA, DeepSeek-V2): K and V come from a
+    # kv_lora_rank-wide latent (cached per token with the shared rotary
+    # key), queries and keys are qk_nope + qk_rope wide, values v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # sequence-chunk for the MoE dispatch: 4096 = unchunked at train_4k
     # (chunking the train path puts one expert-grad all-reduce per chunk in
     # the backward — measured 4.6 TB/step on grok; §Perf iteration 5);
@@ -47,6 +69,15 @@ class ModelConfig:
     vision_dim: int = 1152
     # misc
     rope_theta: float = 10000.0
+    # YaRN rotary scaling (yarn_factor 0 = plain rotary): frequencies
+    # blended between interpolated and original over the correction range
+    # of beta_fast .. beta_slow rotations within yarn_original_max_pos
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     attn_chunk: int = 512
@@ -63,6 +94,9 @@ class ModelConfig:
     subquadratic: bool = False
 
     def __post_init__(self):
+        # list-valued fields (from JSON) become tuples, so a config stays
+        # hashable as a static jit argument
+        object.__setattr__(self, "block_pattern", tuple(self.block_pattern))
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim",
                                self.d_model // max(self.n_heads, 1))
@@ -83,18 +117,24 @@ class ModelConfig:
 
     @property
     def n_super(self) -> int:
-        return self.n_layers // self.pattern_len
+        return (self.n_layers - self.n_dense_layers) // self.pattern_len
 
     @property
     def prefix_pattern(self) -> Tuple[str, ...]:
-        rem = self.n_layers % self.pattern_len
-        return self.block_pattern[:rem]
+        """The unrolled layers before the scanned superblocks: the
+        leading dense layers, then the remainder of the pattern."""
+        rem = (self.n_layers - self.n_dense_layers) % self.pattern_len
+        dense = "mla" if self.kv_lora_rank else "attn"
+        return (dense,) * self.n_dense_layers + self.block_pattern[:rem]
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     def reduced(self, **overrides) -> "ModelConfig":
         """Small same-family variant for CPU smoke tests."""
+        n_experts = min(self.n_experts, 4) if self.n_experts else 0
         changes = dict(
-            n_layers=min(self.n_layers, 2 * self.pattern_len
-                         + len(self.prefix_pattern) % self.pattern_len),
             d_model=64,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads
@@ -102,7 +142,10 @@ class ModelConfig:
             head_dim=16,
             d_ff=128,
             vocab_size=256,
-            n_experts=min(self.n_experts, 4) if self.n_experts else 0,
+            n_experts=n_experts,
+            experts_per_token=min(self.experts_per_token,
+                                  max(1, n_experts // 2)),
+            moe_d_ff=32 if self.moe_d_ff else 0,
             rnn_width=64 if self.rnn_width else 0,
             vision_dim=32,
             vision_tokens=8,
@@ -112,10 +155,13 @@ class ModelConfig:
             param_dtype="float32",
             dtype="float32",
         )
-        # keep prefix-layer structure representative: n_layers =
-        # 2 superblocks + original remainder
-        rem = self.n_layers % self.pattern_len
-        changes["n_layers"] = 2 * self.pattern_len + rem
+        if self.kv_lora_rank:
+            changes.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                           qk_rope_head_dim=8, v_head_dim=16)
+        # keep prefix-layer structure representative: n_layers = the
+        # leading dense layers + 2 superblocks + the original remainder
+        rem = (self.n_layers - self.n_dense_layers) % self.pattern_len
+        changes["n_layers"] = self.n_dense_layers + 2 * self.pattern_len + rem
         changes.update(overrides)
         return dataclasses.replace(self, **changes)
 
@@ -132,20 +178,35 @@ class ModelConfig:
         if self.frontend == "vision":
             total += self.vision_dim * D
 
+        mult = 3 if self.mlp_kind in ("swiglu", "geglu") else 2
+
         def attn_p():
             return D * (H * hd) + 2 * D * (KV * hd) + (H * hd) * D
 
+        def mla_p():
+            L, R = self.kv_lora_rank, self.qk_rope_head_dim
+            q_hd = self.qk_nope_head_dim + R
+            return (D * H * q_hd + D * (L + R) + L
+                    + L * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * D)
+
         def mlp_p():
-            mult = 3 if self.mlp_kind in ("swiglu", "geglu") else 2
             return mult * D * F
+
+        def moe_p():
+            Fe = self.expert_d_ff
+            return (D * self.n_experts + self.n_experts * mult * D * Fe
+                    + self.n_shared_experts * mult * D * Fe)
 
         def block_p(kind):
             if kind == "attn" or kind == "attn_local":
                 return attn_p() + mlp_p() + 2 * D
             if kind == "moe":
-                mult = 3 if self.mlp_kind in ("swiglu", "geglu") else 2
-                return attn_p() + D * self.n_experts \
-                    + self.n_experts * mult * D * F + 2 * D
+                return attn_p() + moe_p() + 2 * D
+            if kind == "mla":
+                return mla_p() + mlp_p() + 2 * D
+            if kind == "mla_moe":
+                return mla_p() + moe_p() + 2 * D
             if kind == "rwkv":
                 dh = H * hd
                 tmix = 4 * D * dh + dh * D + 64 * (D + dh) + dh
@@ -171,9 +232,10 @@ class ModelConfig:
             return self.param_count()
         full = self.param_count()
         mult = 3 if self.mlp_kind in ("swiglu", "geglu") else 2
-        per_expert = mult * self.d_model * self.d_ff
-        n_moe_layers = (self.block_pattern.count("moe") * self.n_super
-                        + self.prefix_pattern.count("moe"))
+        per_expert = mult * self.d_model * self.expert_d_ff
+        n_moe_layers = sum(
+            (self.block_pattern.count(k) * self.n_super
+             + self.prefix_pattern.count(k)) for k in MOE_KINDS)
         inactive = n_moe_layers * (self.n_experts
                                    - self.experts_per_token) * per_expert
         return full - inactive

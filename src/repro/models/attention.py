@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models.layers import apply_rope, init_dense
+from repro.models.layers import (apply_rope, init_dense, rms_norm,
+                                 yarn_freqs, yarn_mscale, yarn_rope_scale)
 
 NEG_INF = -1e30
 
@@ -106,7 +107,7 @@ def _gqa_out(p, v):
 
 
 def chunked_causal_attention(q, k, v, *, chunk: int = 512, window: int = 0,
-                             prefix_len: int = 0):
+                             prefix_len: int = 0, scale=None):
     """Exact causal attention, scanned over query chunks.
 
     window > 0 => local attention (each query sees the last `window` keys).
@@ -118,10 +119,11 @@ def chunked_causal_attention(q, k, v, *, chunk: int = 512, window: int = 0,
     for long even sequences). The same `cfg.attn_chunk` knob also sets
     the KV band size of the serving-side streamed paged prefill
     (streamed_paged_attention / kernels/paged_prefill.py), so one config
-    value bounds score-tile memory on both paths.
+    value bounds score-tile memory on both paths. `scale` defaults to
+    hd ** -0.5; values may be narrower than queries and keys (MLA).
     """
     B, S, H, hd = q.shape
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     chunk = min(chunk, S)
     n_chunks = -(-S // chunk)
     pad = n_chunks * chunk - S
@@ -147,7 +149,7 @@ def chunked_causal_attention(q, k, v, *, chunk: int = 512, window: int = 0,
 
     out = lax.map(lambda args: one_chunk(*args),
                   (jnp.arange(n_chunks), qc))
-    out = out.transpose(1, 0, 2, 3, 4).reshape(B, n_chunks * chunk, H, hd)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(B, n_chunks * chunk, H, -1)
     return out[:, :S].astype(v.dtype)
 
 
@@ -350,7 +352,8 @@ def write_kv(cache, layer, rows, k, v):
 
 def streamed_paged_attention(q, k, v, cache, block_tables, positions,
                              starts, lengths, *, scale, attn_chunk,
-                             block_size, window: int = 0, layer=None):
+                             block_size, window: int = 0, layer=None,
+                             expand=None):
     """Online-softmax suffix-prefill attention over a paged KV cache.
 
     q: (N, Ls, H, hd) rope'd suffix queries; k/v: (N, Ls, KV, hd) rope'd
@@ -367,7 +370,10 @@ def streamed_paged_attention(q, k, v, cache, block_tables, positions,
     — never the full O(N*H*Ls*(M*bs + Ls)) dense tensor. Doubles as the
     interpret-mode oracle for kernels/paged_prefill.py.
 
-    Returns the normalized attention output (N, Ls, H, hd) float32.
+    `expand` (MLA) turns a band's gathered pool rows (N, cb*bs, C) into
+    its keys and values; without it the rows are K|V rows (read_kv).
+
+    Returns the normalized attention output (N, Ls, H, hd_v) float32.
     """
     N, Ls, H, hd = q.shape
     bs = block_size
@@ -395,7 +401,10 @@ def streamed_paged_attention(q, k, v, cache, block_tables, positions,
 
     def band(stats, inp):
         bi, btc = inp                                        # btc: (N, cb)
-        gk, gv = read_kv(cache, layer, btc, bs, hd)          # (N, cb*bs, ..)
+        if expand is None:
+            gk, gv = read_kv(cache, layer, btc, bs, hd)      # (N, cb*bs, ..)
+        else:
+            gk, gv = expand(_gather_blocks(cache["kv"], layer, btc, bs))
         kpos = bi * cb * bs + jnp.arange(cb * bs)
         m = (kpos[None, None, :] < starts[:, None, None])    # (N, 1, cb*bs)
         if window > 0:
@@ -406,7 +415,7 @@ def streamed_paged_attention(q, k, v, cache, block_tables, positions,
 
     init = (jnp.full((N, H, Ls), NEG_INF, jnp.float32),
             jnp.zeros((N, H, Ls), jnp.float32),
-            jnp.zeros((N, Ls, H, hd), jnp.float32))
+            jnp.zeros((N, Ls, H, v.shape[-1]), jnp.float32))
     pre, _ = lax.scan(band, init, (jnp.arange(nb), bt))
     m, l, acc = _merge_stats(pre, suf)
     return acc / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
@@ -501,3 +510,205 @@ def paged_decode_attention_block(params, x, cache, positions, block_tables,
     p = jax.nn.softmax(s, axis=-1)
     o = _gqa_out(p, gv).reshape(B, 1, -1)
     return (o @ params["wo"]).astype(x.dtype), new_cache
+
+
+# ----------------------------------------------------------------------------
+# Multi-head latent attention (MLA, DeepSeek-V2)
+#
+# Per token, x W_kv_a gives a kv_lora_rank-wide latent c (RMS-normed) and
+# one qk_rope-wide rotary key k_pe shared by every head; W_kv_b expands c
+# into each head's non-rotary key and its value. The cache holds one
+# latent row per token, [c | k_pe], kv_lora_rank + qk_rope values (576 in
+# DeepSeek-V2), in a pool row padded to whole 128-lane tiles
+# (mla_pool_width: 640) so that the pool keeps its row-major layout on
+# TPU. Queries are [q_nope | q_pe].
+#
+# Two forms of one attention:
+#   expanded  each key and value is expanded per head from its latent
+#             (the sequence forward and paged prefill, where a chunk's
+#             queries share each expanded key);
+#   absorbed  W_kv_b's key half is folded into the query and its value
+#             half applied after attention, so decode reads each cached
+#             latent row once and never expands per-head keys or values.
+# ----------------------------------------------------------------------------
+
+def init_mla(key, cfg, dtype):
+    D, H, L = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    N, R, V = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {"wq": init_dense(kq, D, H * (N + R), dtype),
+            "wkv_a": init_dense(ka, D, L + R, dtype),
+            "kv_norm": jnp.zeros((L,), dtype),
+            "wkv_b": init_dense(kb, L, H * (N + V), dtype),
+            "wo": init_dense(ko, H * V, D, dtype)}
+
+
+def mla_row_width(cfg) -> int:
+    """Values one token's latent row holds: [c | k_pe]."""
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def mla_pool_width(cfg) -> int:
+    """Columns of a pool row: the latent row padded to 128-lane tiles."""
+    return -(-mla_row_width(cfg) // 128) * 128
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale: (qk_nope + qk_rope)^-1/2 times YaRN's mscale^2."""
+    m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _mla_rope(x, positions, cfg):
+    freqs = yarn_freqs(x.shape[-1], cfg) if cfg.yarn_factor else None
+    out = apply_rope(x, positions, cfg.rope_theta, freqs)
+    m = yarn_rope_scale(cfg) if cfg.yarn_factor else 1.0
+    return out if m == 1.0 else (out * m).astype(out.dtype)
+
+
+def mla_project(params, x, positions, cfg):
+    """x (B, S, D) -> q_nope (B, S, H, N), rope'd q_pe (B, S, H, R) and
+    the tokens' latent rows (B, S, L + R): the normed c, then rope'd
+    k_pe."""
+    B, S, _ = x.shape
+    H, L = cfg.n_heads, cfg.kv_lora_rank
+    N, R = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = (x @ params["wq"]).reshape(B, S, H, N + R)
+    q_pe = _mla_rope(q[..., N:], positions, cfg)
+    kva = x @ params["wkv_a"]
+    c = rms_norm(kva[..., :L], params["kv_norm"], cfg.norm_eps)
+    k_pe = _mla_rope(kva[..., None, L:], positions, cfg)[..., 0, :]
+    return q[..., :N], q_pe, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def mla_expand(params, rows, cfg):
+    """Latent rows (..., S, >= L + R) -> per-head keys (..., S, H, N + R)
+    and values (..., S, H, V)."""
+    H, L = cfg.n_heads, cfg.kv_lora_rank
+    N, R = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope("mla_expand"):
+        kv = rows[..., :L] @ params["wkv_b"].astype(rows.dtype)
+        kv = kv.reshape(*kv.shape[:-1], H, N + cfg.v_head_dim)
+        k_pe = jnp.broadcast_to(rows[..., None, L:L + R],
+                                kv.shape[:-1] + (R,))
+        return jnp.concatenate([kv[..., :N], k_pe], axis=-1), kv[..., N:]
+
+
+def mla_absorbed(params, q_nope, q_pe, rows, valid, cfg):
+    """Absorbed attention over latent rows. q_nope (B, Sq, H, N), q_pe
+    (B, Sq, H, R); rows (B, Sk, C) in the cache dtype, C >= L + R, with
+    finite columns past L + R; valid (B, Sq, Sk) bool. Returns
+    (B, Sq, H * V) float32.
+
+    Scores are [q_nope W_kb | q_pe] . [c | k_pe], over whole rows (the
+    query zero-padded to C, so the rows are read as stored); the output
+    is (p . c) W_vb."""
+    B, Sq, H, N = q_nope.shape
+    L, V = cfg.kv_lora_rank, cfg.v_head_dim
+    C, dt = rows.shape[-1], rows.dtype
+    with jax.named_scope("mla_absorb"):
+        w = params["wkv_b"].reshape(L, H, N + V)
+        q_lat = jnp.einsum("bqhn,lhn->bqhl", q_nope, w[..., :N],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_lat.astype(dt), q_pe.astype(dt)], axis=-1)
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, C - q.shape[-1]),))
+        s = jnp.einsum("bqhc,bsc->bhqs", q, rows,
+                       preferred_element_type=jnp.float32) * mla_scale(cfg)
+        s = jnp.where(valid[:, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bhqs,bsc->bqhc", p.astype(dt), rows,
+                           preferred_element_type=jnp.float32)[..., :L]
+        o = jnp.einsum("bqhl,lhv->bqhv", o_lat.astype(w.dtype), w[..., N:],
+                       preferred_element_type=jnp.float32)
+    return o.reshape(B, Sq, H * V)
+
+
+def mla_block(params, x, positions, cfg, *, return_kv: bool = False):
+    """Sequence form (train / single-shot prefill): expanded, causal.
+    return_kv=True also returns the latent rows (B, S, L + R), what the
+    decode cache stores."""
+    B, S, _ = x.shape
+    q_nope, q_pe, latent = mla_project(params, x, positions, cfg)
+    k, v = mla_expand(params, latent, cfg)
+    o = chunked_causal_attention(jnp.concatenate([q_nope, q_pe], axis=-1),
+                                 k, v, chunk=cfg.attn_chunk,
+                                 scale=mla_scale(cfg))
+    out = o.reshape(B, S, -1) @ params["wo"]
+    if return_kv:
+        return out, latent
+    return out
+
+
+def init_mla_cache(batch, max_len, cfg, dtype):
+    return {"kv": jnp.zeros((batch, max_len, mla_row_width(cfg)), dtype)}
+
+
+def mla_decode_block(params, x, cache, pos, cfg):
+    """One-token decode against a dense latent cache {"kv": (B, S_max,
+    L + R)}; pos: scalar position. Absorbed. Returns (out, new_cache)."""
+    B = x.shape[0]
+    positions = jnp.full((B, 1), pos, dtype=jnp.int32)
+    q_nope, q_pe, latent = mla_project(params, x, positions, cfg)
+    rows = lax.dynamic_update_slice(
+        cache["kv"], latent.astype(cache["kv"].dtype),
+        (0, jnp.asarray(pos, jnp.int32), 0))
+    valid = jnp.broadcast_to(jnp.arange(rows.shape[1]) <= pos,
+                             (B, 1, rows.shape[1]))
+    o = mla_absorbed(params, q_nope, q_pe, rows, valid, cfg)
+    return (o.astype(x.dtype) @ params["wo"]).astype(x.dtype), {"kv": rows}
+
+
+def write_rows(cache, layer, rows, vals):
+    """Land latent rows `vals` (..., L + R) at pool rows `rows` (...),
+    zero-padded to the pool's width. Returns the new cache."""
+    pool = cache["kv"]
+    vals = vals.astype(pool.dtype)
+    pad = pool.shape[-1] - vals.shape[-1]
+    if pad:
+        vals = jnp.pad(vals, ((0, 0),) * (vals.ndim - 1) + ((0, pad),))
+    at = (rows,) if layer is None else (layer, rows)
+    return {**cache, "kv": pool.at[at].set(vals)}
+
+
+def paged_mla_prefill_block(params, x, cache, positions, block_tables,
+                            starts, lengths, cached_lens, cfg, *,
+                            block_size, layer=None):
+    """MLA suffix prefill straight into a latent pool, with the arguments
+    and masking of paged_prefill_attention_block. Expanded: each band of
+    cached latent rows is expanded to per-head keys and values inside the
+    streamed scan, once per dispatch, and the chunk's queries share it.
+    Returns (out, new_cache)."""
+    N, Ls, _ = x.shape
+    bs = block_size
+    M = block_tables.shape[1]
+    q_nope, q_pe, latent = mla_project(params, x, positions, cfg)
+    k, v = mla_expand(params, latent, cfg)
+    o = streamed_paged_attention(
+        jnp.concatenate([q_nope, q_pe], axis=-1), k, v, cache, block_tables,
+        positions, starts, lengths, scale=mla_scale(cfg),
+        attn_chunk=cfg.attn_chunk, block_size=bs, layer=layer,
+        expand=lambda rows: mla_expand(params, rows, cfg))
+    out = (o.reshape(N, Ls, -1).astype(x.dtype) @ params["wo"]).astype(
+        x.dtype)
+    write = jnp.logical_and(positions >= cached_lens[:, None],
+                            positions < lengths[:, None])    # (N, Ls)
+    blk = jnp.take_along_axis(block_tables,
+                              jnp.clip(positions // bs, 0, M - 1), axis=1)
+    blk = jnp.where(write, blk, 0)               # null-sink the rest
+    return out, write_rows(cache, layer, blk * bs + positions % bs, latent)
+
+
+def paged_mla_decode_block(params, x, cache, positions, block_tables, cfg,
+                           *, block_size, layer=None):
+    """One-token MLA decode through a latent pool, with the arguments of
+    paged_decode_attention_block. Absorbed: each lane's cached latent
+    rows are read once, as stored. Returns (out, new_cache)."""
+    B = x.shape[0]
+    bs = block_size
+    q_nope, q_pe, latent = mla_project(params, x, positions[:, None], cfg)
+    rows = block_tables[jnp.arange(B), positions // bs] * bs + positions % bs
+    new_cache = write_rows(cache, layer, rows, latent[:, 0])
+    g = _gather_blocks(new_cache["kv"], layer, block_tables, bs)
+    valid = jnp.arange(g.shape[1])[None, :] <= positions[:, None]
+    o = mla_absorbed(params, q_nope, q_pe, g, valid[:, None, :], cfg)
+    return (o.astype(x.dtype) @ params["wo"]).astype(x.dtype), new_cache

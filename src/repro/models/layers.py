@@ -8,6 +8,8 @@ Conventions:
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -34,10 +36,57 @@ def rope_freqs(head_dim: int, theta: float = 10000.0):
                      / head_dim)
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """x: (..., S, H, hd); positions: (..., S) int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek's yarn_get_mscale)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, orig_max_pos: int,
+                          beta_fast: float, beta_slow: float):
+    """(low, high) frequency-pair indices between which YaRN blends the
+    interpolated and the original frequencies: the pairs that turn
+    beta_fast and beta_slow times over `orig_max_pos` positions."""
+    def pair(rotations):
+        return (dim * math.log(orig_max_pos / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = math.floor(pair(beta_fast))
+    high = math.ceil(pair(beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_freqs(dim: int, cfg):
+    """YaRN's blended inverse frequencies (dim/2,) for a `dim`-wide rotary
+    slice: pairs below the correction range keep their original
+    frequency, pairs above it are divided by the factor, and a linear
+    ramp blends the pairs within it. The rotated values' scale and the
+    attention scale are yarn_rope_scale and attention.mla_scale."""
+    extra = rope_freqs(dim, cfg.rope_theta)
+    low, high = yarn_correction_range(dim, cfg.rope_theta,
+                                      cfg.yarn_original_max_pos,
+                                      cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / cfg.yarn_factor * (1.0 - keep) + extra * keep
+
+
+def yarn_rope_scale(cfg) -> float:
+    """YaRN's scale of the rotated values (DeepSeek's cos/sin `_mscale`):
+    1.0 whenever mscale equals mscale_all_dim, as in DeepSeek-V2."""
+    return (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+            / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+
+
+def apply_rope(x, positions, theta: float = 10000.0, freqs=None):
+    """x: (..., S, H, hd); positions: (..., S) int32. `freqs` (hd/2,)
+    replaces the plain inverse frequencies (YaRN)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]                       # (..., S, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)

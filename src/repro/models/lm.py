@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import MOE_KINDS, ModelConfig
 from repro.distributed import context as dctx
 from repro.models import attention, frontends, moe as moe_lib, recurrent
 from repro.models.layers import (init_embed, init_mlp, mlp, rms_norm,
@@ -62,6 +62,10 @@ def _pin_act(h):
     return jax.lax.with_sharding_constraint(h, spec)
 
 
+# block kinds with multi-head latent attention
+MLA_KINDS = ("mla", "mla_moe")
+
+
 # ----------------------------------------------------------------------------
 # Block init / apply
 # ----------------------------------------------------------------------------
@@ -70,6 +74,14 @@ def _init_block(key, cfg: ModelConfig, kind: str):
     k1, k2 = jax.random.split(key)
     D, dt = cfg.d_model, cfg.p_dtype
     norms = {"norm1": jnp.zeros((D,), dt), "norm2": jnp.zeros((D,), dt)}
+    if kind == "mla":
+        return {**norms, "attn": attention.init_mla(k1, cfg, dt),
+                "mlp": init_mlp(k2, D, cfg.d_ff, dt, cfg.mlp_kind)}
+    if kind == "mla_moe":
+        return {**norms, "attn": attention.init_mla(k1, cfg, dt),
+                "moe": moe_lib.init_moe(k2, D, cfg.expert_d_ff,
+                                        cfg.n_experts, dt, cfg.mlp_kind,
+                                        cfg.n_shared_experts)}
     if kind in ("attn", "attn_local"):
         return {**norms,
                 "attn": attention.init_attention(
@@ -79,8 +91,9 @@ def _init_block(key, cfg: ModelConfig, kind: str):
         return {**norms,
                 "attn": attention.init_attention(
                     k1, D, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, dt),
-                "moe": moe_lib.init_moe(k2, D, cfg.d_ff, cfg.n_experts, dt,
-                                        cfg.mlp_kind)}
+                "moe": moe_lib.init_moe(k2, D, cfg.expert_d_ff,
+                                        cfg.n_experts, dt, cfg.mlp_kind,
+                                        cfg.n_shared_experts)}
     if kind == "rwkv":
         return {**norms,
                 "tmix": recurrent.init_rwkv(k1, D, cfg.n_heads, cfg.head_dim,
@@ -92,6 +105,23 @@ def _init_block(key, cfg: ModelConfig, kind: str):
                                                   cfg.conv_width, dt),
                 "mlp": init_mlp(k2, D, cfg.d_ff, dt, cfg.mlp_kind)}
     raise ValueError(kind)
+
+
+def _ffn(params, kind: str, h, cfg: ModelConfig, *, valid=None,
+         layer=None):
+    """The block's feed-forward on normed h: the MLP, or the experts,
+    routed dropless where the config says so (`cfg.dropless`) and else
+    through the capacity dispatch. With `layer`, the routed experts are
+    the whole layer stack (see _paged_stack). Returns (out, aux,
+    routed-or-None)."""
+    if kind not in MOE_KINDS:
+        return mlp(params["mlp"], h, cfg.mlp_kind), {}, None
+    if cfg.dropless:
+        return moe_lib.moe_dropless(params["moe"], h, cfg,
+                                    kind=cfg.mlp_kind, valid=valid,
+                                    layer=layer)
+    o, aux = moe_lib.moe_block(params["moe"], h, cfg, kind=cfg.mlp_kind)
+    return o, aux, None
 
 
 def _apply_block_seq(params, kind: str, x, positions, cfg: ModelConfig,
@@ -111,7 +141,17 @@ def _apply_block_seq(params, kind: str, x, positions, cfg: ModelConfig,
     aux = {}
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     new_state = state
-    if kind in ("attn", "attn_local"):
+    if kind in MLA_KINDS:
+        o = attention.mla_block(params["attn"], h, positions, cfg,
+                                return_kv=collect_kv)
+        if collect_kv:
+            o, latent = o
+            new_state = {"kv": latent}
+        x = x + o
+        h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
+        o2, aux, _ = _ffn(params, kind, h2, cfg)
+        x = x + o2
+    elif kind in ("attn", "attn_local"):
         window = cfg.window if kind == "attn_local" else 0
         o = attention.attention_block(params["attn"], h, positions, cfg,
                                       window=window, prefix_len=prefix_len,
@@ -131,8 +171,7 @@ def _apply_block_seq(params, kind: str, x, positions, cfg: ModelConfig,
             new_state = {"k": k_seq, "v": v_seq}
         x = x + o
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        o2, aux = moe_lib.moe_block(params["moe"], h2, cfg,
-                                    kind=cfg.mlp_kind)
+        o2, aux, _ = _ffn(params, kind, h2, cfg)
         x = x + o2
     elif kind == "rwkv":
         st_t = None if state is None else state["tmix"]
@@ -379,6 +418,8 @@ def _init_block_state(cfg: ModelConfig, kind: str, batch: int, max_len: int,
     if kind == "moe":
         return attention.init_kv_cache(batch, max_len, cfg.n_kv_heads,
                                        cfg.head_dim, dtype)
+    if kind in MLA_KINDS:
+        return attention.init_mla_cache(batch, max_len, cfg, dtype)
     if kind == "rwkv":
         return {"tmix": {"shift": jnp.zeros((batch, cfg.d_model), dtype),
                          "S": jnp.zeros((batch, cfg.n_heads, cfg.head_dim,
@@ -409,19 +450,18 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int):
 def _apply_block_step(params, kind: str, x, pos, cfg: ModelConfig, state):
     """One-token form. x: (B,1,D)."""
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
-    if kind in ("attn", "attn_local", "moe"):
-        window = cfg.window if kind == "attn_local" else 0
-        o, new_cache = attention.decode_attention_block(
-            params["attn"], h, state, pos, cfg, window=window)
+    if kind in ATTN_KINDS:
+        if kind in MLA_KINDS:
+            o, new_cache = attention.mla_decode_block(params["attn"], h,
+                                                      state, pos, cfg)
+        else:
+            window = cfg.window if kind == "attn_local" else 0
+            o, new_cache = attention.decode_attention_block(
+                params["attn"], h, state, pos, cfg, window=window)
         x = x + o
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        if kind == "moe":
-            o2, _ = moe_lib.moe_block(params["moe"], h2, cfg,
-                                      kind=cfg.mlp_kind)
-            x = x + o2
-        else:
-            x = x + mlp(params["mlp"], h2, cfg.mlp_kind)
-        return x, new_cache
+        o2, _, _ = _ffn(params, kind, h2, cfg)
+        return x + o2, new_cache
     if kind == "rwkv":
         o, st_t = recurrent.rwkv_seq(params["tmix"], h, cfg, state["tmix"])
         x = x + o
@@ -480,7 +520,17 @@ def decode_step(params, cfg: ModelConfig, state, tokens, pos):
 # ----------------------------------------------------------------------------
 
 # block kinds whose KV lives in the paged pools
-ATTN_KINDS = ("attn", "attn_local", "moe")
+ATTN_KINDS = ("attn", "attn_local", "moe", "mla", "mla_moe")
+
+
+def _count_routes(state, layer, routed):
+    """Add a dispatch's routed-token counts (E,) to a MoE layer's device
+    counter (its paged state's "routed", (n_super, E) when stacked); the
+    counters are read back only when the engine is asked for them."""
+    if routed is None:
+        return state
+    at = () if layer is None else (layer,)
+    return {**state, "routed": state["routed"].at[at].add(routed)}
 
 
 def _paged_stack(params, cfg: ModelConfig, state, h, apply):
@@ -505,10 +555,26 @@ def _paged_stack(params, cfg: ModelConfig, state, h, apply):
     pools = {k: v for k, v in state["blocks"].items() if k in pooled}
     slot_state = {k: v for k, v in state["blocks"].items()
                   if k not in pooled}
+    # a stacked MoE layer's routed experts stay out of the scan's xs and
+    # each layer reads them whole, at its index (moe.split_stacked_experts)
+    blocks, experts = dict(params["blocks"]), {}
+    for pi, kind in enumerate(cfg.block_pattern):
+        if kind in MOE_KINDS:
+            if not cfg.dropless:
+                raise ValueError(
+                    "the paged serving paths route MoE dropless: set "
+                    "ModelConfig.dropless (ServingEngine does)")
+            key = f"p{pi}"
+            rest, experts[key] = moe_lib.split_stacked_experts(
+                blocks[key]["moe"])
+            blocks[key] = {**blocks[key], "moe": rest}
 
     def superblock(carry, xs):
         h, pools = carry
         block_params, slot_state, layer = xs
+        block_params = {k: ({**p, "moe": {**p["moe"], **experts[k]}}
+                            if k in experts else p)
+                        for k, p in block_params.items()}
         block_params = _pin_block(block_params)
         h = _pin_act(h)
         pools = dict(pools)
@@ -525,8 +591,7 @@ def _paged_stack(params, cfg: ModelConfig, state, h, apply):
 
     (h, pools), (new_slot, block_snaps) = lax.scan(
         superblock, (h, pools),
-        (params["blocks"], slot_state,
-         jnp.arange(cfg.n_super, dtype=jnp.int32)))
+        (blocks, slot_state, jnp.arange(cfg.n_super, dtype=jnp.int32)))
     return (h, {"prefix": new_prefix, "blocks": {**new_slot, **pools}},
             {"prefix": prefix_snaps, "blocks": block_snaps})
 
@@ -536,22 +601,25 @@ def _apply_block_step_paged(params, kind: str, x, positions,
                             block_size: int, layer=None):
     """One-token step against paged attention state. x: (B,1,D);
     positions: (B,) per-slot. Non-attention layers keep slot-indexed dense
-    state (O(B) per layer) and ignore positions."""
+    state (O(B) per layer) and ignore positions. Idle lanes (an all-null
+    table row) are computed and not counted in the expert counters."""
     if kind in ATTN_KINDS:
         h = rms_norm(x, params["norm1"], cfg.norm_eps)
-        window = cfg.window if kind == "attn_local" else 0
-        o, new_cache = attention.paged_decode_attention_block(
-            params["attn"], h, state, positions, block_tables, cfg,
-            block_size=block_size, window=window, layer=layer)
+        if kind in MLA_KINDS:
+            o, new_cache = attention.paged_mla_decode_block(
+                params["attn"], h, state, positions, block_tables, cfg,
+                block_size=block_size, layer=layer)
+        else:
+            window = cfg.window if kind == "attn_local" else 0
+            o, new_cache = attention.paged_decode_attention_block(
+                params["attn"], h, state, positions, block_tables, cfg,
+                block_size=block_size, window=window, layer=layer)
         x = x + o
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        if kind == "moe":
-            o2, _ = moe_lib.moe_block(params["moe"], h2, cfg,
-                                      kind=cfg.mlp_kind)
-            x = x + o2
-        else:
-            x = x + mlp(params["mlp"], h2, cfg.mlp_kind)
-        return x, new_cache
+        valid = (block_tables[:, :1] != 0) if kind in MOE_KINDS else None
+        o2, _, routed = _ffn(params, kind, h2, cfg, valid=valid,
+                             layer=layer)
+        return x + o2, _count_routes(new_cache, layer, routed)
     # rwkv / rec: position-independent recurrences; reuse the dense step
     return _apply_block_step(params, kind, x, 0, cfg, state)
 
@@ -574,20 +642,24 @@ def _apply_block_prefill_paged(params, kind: str, x, positions,
     slot, where the previous chunk's dispatch scattered it."""
     if kind in ATTN_KINDS:
         h = rms_norm(x, params["norm1"], cfg.norm_eps)
-        window = cfg.window if kind == "attn_local" else 0
-        o, new_cache = attention.paged_prefill_attention_block(
-            params["attn"], h, state, positions, block_tables, starts,
-            lengths, cached_lens, cfg, block_size=block_size, window=window,
-            layer=layer)
+        if kind in MLA_KINDS:
+            o, new_cache = attention.paged_mla_prefill_block(
+                params["attn"], h, state, positions, block_tables, starts,
+                lengths, cached_lens, cfg, block_size=block_size,
+                layer=layer)
+        else:
+            window = cfg.window if kind == "attn_local" else 0
+            o, new_cache = attention.paged_prefill_attention_block(
+                params["attn"], h, state, positions, block_tables, starts,
+                lengths, cached_lens, cfg, block_size=block_size,
+                window=window, layer=layer)
         x = x + o
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        if kind == "moe":
-            o2, _ = moe_lib.moe_block(params["moe"], h2, cfg,
-                                      kind=cfg.mlp_kind)
-            x = x + o2
-        else:
-            x = x + mlp(params["mlp"], h2, cfg.mlp_kind)
-        return x, new_cache
+        valid = (positions < lengths[:, None]) if kind in MOE_KINDS \
+            else None
+        o2, _, routed = _ffn(params, kind, h2, cfg, valid=valid,
+                             layer=layer)
+        return x + o2, _count_routes(new_cache, layer, routed)
     # rwkv / rec: run over the chunk, freeze past length
     init = None
     if resume:

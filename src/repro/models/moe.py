@@ -1,4 +1,5 @@
-"""Top-k routed Mixture-of-Experts with static capacity dispatch.
+"""Top-k routed Mixture-of-Experts: static capacity dispatch, and dropless
+grouped dispatch for configs with `dropless` set (every served MoE).
 
 Classic dispatch/combine formulation (Mesh-TF / GShard style) chosen because
 it is fully static-shaped (compiles under pjit for any mesh) and the dispatch
@@ -9,6 +10,14 @@ transient footprint is O(chunk * E * C), not O(S * E * C) — required for the
 
 Aux losses: load-balancing loss (Switch) + router z-loss, returned to the
 caller for logging / the training objective.
+
+Dropless (`moe_dropless`): every token reaches all k of its experts,
+whatever else is in the batch, so a sequence's output never depends on
+the rows it is batched with. The (token, expert) pairs are sorted by
+expert and each expert's rows go through one grouped matrix product
+(`lax.ragged_dot`), never a dense product over all experts. Shared
+experts (`n_shared_experts`, DeepSeek) are one always-on SwiGLU of
+n_shared * expert width, added unweighted, on both paths.
 """
 from __future__ import annotations
 
@@ -17,10 +26,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.distributed import context as dctx
-from repro.models.layers import init_dense
+from repro.models.layers import init_dense, init_mlp, mlp
 
 
-def init_moe(key, d_model, d_ff, n_experts, dtype, kind: str = "swiglu"):
+def init_moe(key, d_model, d_ff, n_experts, dtype, kind: str = "swiglu",
+             n_shared: int = 0):
     kr, k1, k2, k3 = jax.random.split(key, 4)
     E = n_experts
     p = {"router": init_dense(kr, d_model, E, jnp.float32),
@@ -31,6 +41,9 @@ def init_moe(key, d_model, d_ff, n_experts, dtype, kind: str = "swiglu"):
     if kind in ("swiglu", "geglu"):
         p["w_gate"] = (jax.random.normal(k1, (E, d_model, d_ff))
                        * d_model**-0.5).astype(dtype)
+    if n_shared:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 4), d_model,
+                               n_shared * d_ff, dtype, kind)
     return p
 
 
@@ -142,4 +155,84 @@ def moe_block(params, x, cfg, *, kind: str = "swiglu"):
 
     ys, auxs, zs = lax.map(one_chunk, xc)
     y = ys.transpose(1, 0, 2, 3).reshape(B, n_chunks * chunk, D)[:, :S]
+    if "shared" in params:
+        y = y + mlp(params["shared"], x[:, :S], kind)
     return y, {"moe_aux": auxs.mean(), "moe_zloss": zs.mean()}
+
+
+# the routed experts' weights, (E, ...) per layer
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def split_stacked_experts(params):
+    """A layer stack's MoE params, (n_stack, ...) per leaf, as (the rest,
+    which a layer loop slices per layer, and the routed experts, held
+    whole). Each layer's dispatch reads the experts at its index
+    (moe_dropless's `layer`, with the two merged back into its params):
+    a grouped product cannot fuse a dynamic slice of its weights, so a
+    sliced layer's experts would be copied every dispatch."""
+    experts = {n: a for n, a in params.items() if n in EXPERT_LEAVES}
+    return {n: a for n, a in params.items() if n not in experts}, experts
+
+
+def moe_dropless(params, x, cfg, *, kind: str = "swiglu", valid=None,
+                 layer=None):
+    """Dropless top-k MoE over the tokens of x (..., D).
+
+    Router: softmax over the experts in float32, greedy top-k, the gates
+    the chosen experts' probabilities (not renormalised, unscaled, as
+    moe_block routes). Dispatch: the T * k (token, expert) pairs
+    sorted by expert, each expert's rows through one grouped product per
+    weight (lax.ragged_dot), the results weighted by their gates and
+    summed back per token; plus the shared experts. `valid` (x.shape[:-1]
+    bool, default all) marks the tokens that count: padding and idle
+    lanes are computed but not counted.
+
+    With `layer` (a traced index), the expert weights are a whole layer
+    stack, (n_stack, E, ...), taken as n_stack * E groups of which only
+    this layer's E have rows: the grouped products read this layer's
+    experts in place, where a slice of the stack would copy them (the
+    grouped product cannot fuse a dynamic slice of its weights).
+
+    Returns (y (..., D) in x.dtype, aux losses, routed (E,) int32: the
+    valid tokens' routes per expert)."""
+    lead, D = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, D)
+    T = xt.shape[0]
+    E, k = cfg.n_experts, cfg.experts_per_token
+    with jax.named_scope("moe_route"):
+        logits = xt.astype(jnp.float32) @ params["router"].astype(
+            jnp.float32)                                     # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = lax.top_k(probs, k)                     # (T, k)
+    with jax.named_scope("moe_dispatch"):
+        flat = idx.reshape(T * k)
+        order = jnp.argsort(flat, stable=True)               # by expert
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        w = {n: params[n] for n in EXPERT_LEAVES if n in params}
+        groups = sizes
+        if layer is not None:
+            n_stack = w["w_up"].shape[0]
+            w = {n: a.reshape(n_stack * E, *a.shape[2:])
+                 for n, a in w.items()}
+            groups = lax.dynamic_update_slice(
+                jnp.zeros((n_stack * E,), jnp.int32), sizes, (layer * E,))
+        xs = xt[order // k]                                  # (T*k, D)
+        if "w_gate" in w:                # gated, as moe_block computes it
+            h = (jax.nn.silu(lax.ragged_dot(xs, w["w_gate"], groups))
+                 * lax.ragged_dot(xs, w["w_up"], groups))
+        else:
+            h = jax.nn.gelu(lax.ragged_dot(xs, w["w_up"], groups))
+        ys = lax.ragged_dot(h, w["w_down"], groups,
+                            preferred_element_type=jnp.float32)
+        ys = ys[jnp.argsort(order)].reshape(T, k, D)         # token-major
+        y = jnp.einsum("tkd,tk->td", ys, gates)
+    if "shared" in params:
+        y = y + mlp(params["shared"], xt, kind).astype(jnp.float32)
+    ok = (jnp.ones((T,), jnp.int32) if valid is None
+          else valid.reshape(T).astype(jnp.int32))
+    routed = jnp.zeros((E,), jnp.int32).at[flat].add(jnp.repeat(ok, k))
+    frac = sizes.astype(jnp.float32) / (T * k)
+    aux = {"moe_aux": E * jnp.sum(frac * probs.mean(0)),
+           "moe_zloss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)}
+    return y.astype(x.dtype).reshape(*lead, D), aux, routed

@@ -166,6 +166,10 @@ class ServingEngine:
                 sampling = SamplingParams(temperature=temperature or 0.0,
                                           seed=seed or 0)
         self.default_sampling = sampling or SamplingParams()
+        if cfg.n_experts and not cfg.dropless:
+            # a served MoE routes dropless, so that a request's output
+            # never depends on what else is in its dispatch
+            cfg = dataclasses.replace(cfg, dropless=True)
         attn_only = all(k in ATTN_KINDS
                         for k in cfg.block_pattern + cfg.prefix_pattern)
         if prefix_cache and not attn_only:
@@ -273,6 +277,20 @@ class ServingEngine:
             self.obs.gauge("kv_device_bytes_gauge").set(self.cache_bytes)
             self.obs.gauge("kv_host_bytes_gauge").set(
                 self.host_cache_blocks * self.runner.block_bytes)
+
+    def read_expert_counts(self) -> Optional[np.ndarray]:
+        """Routed tokens per MoE layer and expert since begin_run, read
+        from the device (one read, only here: the dispatches accumulate
+        them in the paged state and never sync for them) into the
+        registry as `moe_routed_tokens{layer, expert}` gauges. Returns
+        the (n_moe_layers, E) counts, or None without MoE layers."""
+        counts = self.runner.routed_counts()
+        if counts is not None and self.obs.enabled:
+            for layer, row in enumerate(counts):
+                for expert, n in enumerate(row):
+                    self.obs.gauge("moe_routed_tokens", layer=layer,
+                                   expert=expert).set(int(n))
+        return counts
 
     def align_clock(self, t0: float) -> None:
         """Adopt a cluster clock origin WITHOUT resetting telemetry —

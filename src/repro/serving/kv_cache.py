@@ -19,7 +19,10 @@ Layer-state layout (the layer tree of models/lm.init_decode_state):
                      columns and V in the last (models/attention.py's
                      row form); stacked layers carry a leading n_super
                      axis, and the layer loop carries that array and
-                     updates it in place;
+                     updates it in place. An MLA layer's row is its
+                     token's latent [c | k_pe], padded to 128-lane
+                     tiles; a MoE layer's state also holds "routed",
+                     its per-expert routed-token counters;
   recurrent layers   slot-indexed dense state, (num_slots, ...) per leaf —
                      O(num_slots), no paging needed.
 
@@ -39,7 +42,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import MOE_KINDS, ModelConfig
 from repro.models import attention, lm
 # BlockAllocator grew refcounts + the prefix-cache index and moved to its
 # own layer; re-exported here for backward compatibility.
@@ -48,6 +51,10 @@ from repro.serving.block_manager import NULL_BLOCK, BlockAllocator  # noqa: F401
 # block kinds whose KV lives in the paged pools (canonical set —
 # the engine's prefix-cache gate and copy_block both key off it)
 ATTN_KINDS = lm.ATTN_KINDS
+
+# the leaves of an attention layer's state that are pool rows (a MoE
+# layer's state also holds its expert counters, "routed": (E,) int32)
+POOL_LEAVES = ("kv", "kv_scale")
 
 # pool precisions: "fp16" is the activation dtype (bit-identical default);
 # the quantized modes carry per-slot scale side-tables.
@@ -76,12 +83,21 @@ def init_paged_state(cfg: ModelConfig, num_slots: int, num_blocks: int,
     pool_dt = pool_dtype(cfg, kv_dtype)
     rows = num_blocks * block_size
 
+    if quantized(kv_dtype) and cfg.kv_lora_rank:
+        raise ValueError(
+            f"kv_dtype {kv_dtype!r}: a latent (MLA) pool has no quantized "
+            f"form yet (int8/fp8 pools keep one scale per KV head, and a "
+            f"latent row has no heads); serve MLA models with kv_dtype "
+            f"'fp16'")
+
     def layer_state(kind, lead=()):
         if kind in ATTN_KINDS:
             st = {"kv": jnp.zeros(lead + (rows, _row_width(cfg)), pool_dt)}
             if quantized(kv_dtype):
                 st["kv_scale"] = jnp.zeros(lead + (rows, 2 * cfg.n_kv_heads),
                                            jnp.float32)
+            if kind in MOE_KINDS:
+                st["routed"] = jnp.zeros(lead + (cfg.n_experts,), jnp.int32)
             return st
         one = lm._init_block_state(cfg, kind, num_slots, 0, dt)
         return jax.tree.map(lambda x: jnp.zeros(lead + x.shape, x.dtype),
@@ -93,7 +109,10 @@ def init_paged_state(cfg: ModelConfig, num_slots: int, num_blocks: int,
 
 
 def _row_width(cfg: ModelConfig) -> int:
-    """Columns of one token's pool row: its K and V heads side by side."""
+    """Columns of one token's pool row: its K and V heads side by side,
+    or an MLA model's latent row padded to 128-lane tiles."""
+    if cfg.kv_lora_rank:
+        return attention.mla_pool_width(cfg)
     return 2 * cfg.n_kv_heads * cfg.head_dim
 
 
@@ -105,8 +124,8 @@ def _n_attn_layers(cfg: ModelConfig) -> int:
 def paged_bytes(cfg: ModelConfig, num_blocks: int, block_size: int,
                 kv_dtype: str = "fp16") -> int:
     """Attention-cache bytes of the pool (the memory the paging bounds):
-    one row of 2 * KV * hd elements per token in the pool dtype, plus the
-    f32 scale row of a quantized pool."""
+    one row per token as stored (2 * KV * hd elements, or a padded latent
+    row) in the pool dtype, plus the f32 scale row of a quantized pool."""
     per_tok = _row_width(cfg) * pool_dtype(cfg, kv_dtype).itemsize
     if quantized(kv_dtype):
         per_tok += 2 * cfg.n_kv_heads * 4      # f32 scale per (slot, head)
@@ -151,8 +170,9 @@ def copy_block(cfg: ModelConfig, state, src, dst, block_size: int):
     def copy_layer(kind, st, stacked):
         if kind not in ATTN_KINDS:
             return st
-        return {n: a.at[_at_rows(a, dst_rows, stacked)].set(
-            a[_at_rows(a, src_rows, stacked)]) for n, a in st.items()}
+        return {**st, **{n: a.at[_at_rows(a, dst_rows, stacked)].set(
+            a[_at_rows(a, src_rows, stacked)]) for n, a in st.items()
+            if n in POOL_LEAVES}}
 
     new_prefix = [copy_layer(kind, st, False)
                   for kind, st in zip(cfg.prefix_pattern, state["prefix"])]
@@ -179,6 +199,18 @@ def load_prefill(cfg: ModelConfig, state, cache, slot, table_row,
     quantize on landing, scattering (q, scale) per token slot.
     """
     def load_layer(kind, st, ca, stacked):
+        if kind in ATTN_KINDS and "kv" in ca:
+            # an MLA layer's latent rows: (B=1, P, L + R), stacked
+            # (n_super, 1, P, L + R)
+            lat = ca["kv"][:, 0] if stacked else ca["kv"][0]
+            pos = jnp.arange(lat.shape[-2])
+            rows = table_row[pos // block_size] * block_size + (
+                pos % block_size)
+            if not stacked:
+                return attention.write_rows(st, None, rows, lat)
+            for layer in range(lat.shape[0]):
+                st = attention.write_rows(st, layer, rows, lat[layer])
+            return st
         if kind in ATTN_KINDS:
             # ca k/v: (B=1, P, KV, hd), stacked: (n_super, 1, P, KV, hd)
             k, v = (ca["k"][:, 0], ca["v"][:, 0]) if stacked else (
@@ -224,10 +256,11 @@ def gather_blocks(cfg: ModelConfig, state, ids, block_size: int):
     def g(kind, st, stacked):
         if kind not in ATTN_KINDS:
             return {}
+        pool = {n: a for n, a in st.items() if n in POOL_LEAVES}
         if stacked:
             return {n: jnp.moveaxis(a[_at_rows(a, rows, True)], 1, 0)
-                    for n, a in st.items()}
-        return {n: a[rows] for n, a in st.items()}
+                    for n, a in pool.items()}
+        return {n: a[rows] for n, a in pool.items()}
 
     prefix = [g(kind, st, False)
               for kind, st in zip(cfg.prefix_pattern, state["prefix"])]
@@ -245,11 +278,11 @@ def scatter_blocks(cfg: ModelConfig, state, ids, payload, block_size: int):
         if kind not in ATTN_KINDS:
             return st
         if stacked:
-            return {n: a.at[_at_rows(a, rows, True)].set(
+            return {**st, **{n: a.at[_at_rows(a, rows, True)].set(
                 jnp.moveaxis(pa[n], 0, 1).astype(a.dtype))
-                for n, a in st.items()}
-        return {n: a.at[rows].set(pa[n].astype(a.dtype))
-                for n, a in st.items()}
+                for n, a in st.items() if n in POOL_LEAVES}}
+        return {**st, **{n: a.at[rows].set(pa[n].astype(a.dtype))
+                         for n, a in st.items() if n in POOL_LEAVES}}
 
     new_prefix = [s(kind, st, pa, False)
                   for kind, st, pa in zip(cfg.prefix_pattern,

@@ -98,6 +98,17 @@ class PrefillRow:
         return len(self.tokens) - self.start
 
 
+def _map_routed(state, fn):
+    """The paged state with fn applied to every MoE layer's expert
+    counters (their "routed" leaves); the same state without MoE."""
+    def one(st):
+        if isinstance(st, dict) and "routed" in st:
+            return {**st, "routed": fn(st["routed"])}
+        return st
+    return {"prefix": [one(st) for st in state["prefix"]],
+            "blocks": {k: one(st) for k, st in state["blocks"].items()}}
+
+
 class ModelRunner:
     """Owns device state + jitted dispatch. See module docstring."""
 
@@ -284,6 +295,7 @@ class ModelRunner:
         self._upload_fn = jax.jit(_upload, donate_argnums=(0,))
 
     def reset_stats(self) -> None:
+        self.state = _map_routed(self.state, jnp.zeros_like)
         self.prefill_dispatches = 0
         self.prefill_padded_tokens = 0       # token slots incl. padding
         self.prefill_computed_tokens = 0     # true suffix tokens computed
@@ -293,6 +305,22 @@ class ModelRunner:
         self.verify_padded_tokens = 0        # chain slots incl. padding
         self.verify_chain_tokens = 0         # true chain tokens verified
         self.sampled_dispatches = 0          # decode/verify full-sampler uses
+
+    def routed_counts(self) -> Optional[np.ndarray]:
+        """Routed-token counts per MoE layer and expert, (n_moe_layers,
+        E) in layer order, accumulated on the device by every prefill,
+        decode and verify dispatch since the last reset_stats: one
+        device read, made only when asked. None without MoE layers."""
+        rows = [st["routed"][None] for st in self.state["prefix"]
+                if "routed" in st]
+        stacked = [st["routed"] for st in self.state["blocks"].values()
+                   if "routed" in st]
+        if stacked:       # superblock-major: layer j of every position
+            rows.append(jnp.stack(stacked, 1).reshape(-1,
+                                                      self.cfg.n_experts))
+        if not rows:
+            return None
+        return np.asarray(jnp.concatenate(rows))
 
     # ------------------------------------------------------------------
     # block tables

@@ -407,12 +407,15 @@ def test_prefill_length_masked(arch):
                                        atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("arch", ["smollm-135m", "recurrentgemma-2b"])
+@pytest.mark.parametrize("arch", ["smollm-135m", "recurrentgemma-2b",
+                                  "deepseek-v2-lite"])
 def test_prefill_paged_matches_per_sequence_load_prefill(arch):
     """The fused batched path (lm.prefill_paged) must leave the paged
     state identical to the per-sequence oracle (lm.prefill + kv_cache.
-    load_prefill) — same KV in every block it owns, same recurrent slot
-    state, same last-token logits."""
+    load_prefill) — same KV (or MLA latent rows) in every block it owns,
+    same recurrent slot state, same last-token logits. A MoE layer's
+    expert counters count what a dispatch computed, which the oracle
+    does not, so they are not compared."""
     from repro.serving import kv_cache
     cfg = get_config(arch).reduced()
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
@@ -447,6 +450,8 @@ def test_prefill_paged_matches_per_sequence_load_prefill(arch):
     for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(fused),
                                  jax.tree.leaves(oracle)):
         got, want = np.asarray(got), np.asarray(want)
+        if path[-1] == jax.tree_util.DictKey("routed"):
+            continue
         if path[-1] == jax.tree_util.DictKey("kv"):   # (.., 6 * bs, C)
             np.testing.assert_allclose(got[..., bs:, :], want[..., bs:, :],
                                        atol=1e-4, rtol=1e-4)

@@ -16,7 +16,10 @@ published widths:
     sampled, one prefill bucket, verify, copy-on-write and host-tier
     upload) at 64 lanes and 4097 blocks of 16, depth cut to 4 layers:
     none may copy, slice or rebuild the pool, and each must take and
-    return it in the same row-major format.
+    return it in the same row-major format;
+  * the same for DeepSeek-V2-Lite's latent (MLA) pool at its published
+    widths, depth cut to one dense and two MoE layers: rows of 576
+    values stored padded to 640 lanes.
 
 Nothing runs, so these say nothing about results or times. The topology
 is described inside a module fixture, never at import: only one process
@@ -204,14 +207,28 @@ def _pool_formats(formats, state):
 
 @pytest.fixture(scope="module")
 def pool_programs(one_chip, no_persistent_cache):
-    """Lowers every runner program that takes the pool, at smollm-135m
-    widths and 4 layers. The runner is built over a 2-block pool (no
-    1.5 GB pool is allocated here); its programs are lowered for the
-    full 4097-block state as shapes on the chip."""
-    cfg = dataclasses.replace(SMOLLM, n_layers=4)
+    """Every runner program that takes the pool, at smollm-135m widths
+    and 4 layers, 64 lanes."""
+    return _programs(dataclasses.replace(SMOLLM, n_layers=4), one_chip,
+                     LANES)
+
+
+@pytest.fixture(scope="module")
+def mla_pool_programs(one_chip, no_persistent_cache):
+    """The same for DeepSeek-V2-Lite at published widths (bfloat16
+    weights), one dense and two MoE layers, 32 lanes."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), n_layers=3)
+    return _programs(cfg, one_chip, 32)
+
+
+def _programs(cfg, one_chip, n_lanes):
+    """Lowers every runner program that takes the pool. The runner is
+    built over a 2-block pool (no pool is allocated here); its programs
+    are lowered for the full 4097-block state as shapes on the chip."""
     param_shapes = jax.eval_shape(
         lambda: lm.init_params(jax.random.PRNGKey(0), cfg))
-    runner = ModelRunner(param_shapes, cfg, num_slots=LANES, block_size=BS,
+    runner = ModelRunner(param_shapes, cfg, num_slots=n_lanes,
+                         block_size=BS,
                          num_blocks=2, max_blocks_per_seq=MB, speculate=3)
 
     def on_chip(x):
@@ -219,10 +236,10 @@ def pool_programs(one_chip, no_persistent_cache):
 
     P = jax.tree.map(on_chip, param_shapes)
     S = jax.tree.map(on_chip, jax.eval_shape(
-        lambda: kv_cache.init_paged_state(cfg, LANES, NB, BS)))
-    lanes = _sds(one_chip, (LANES,), jnp.int32)
-    temps = _sds(one_chip, (LANES,), jnp.float32)
-    tables = _sds(one_chip, (LANES, MB), jnp.int32)
+        lambda: kv_cache.init_paged_state(cfg, n_lanes, NB, BS)))
+    lanes = _sds(one_chip, (n_lanes,), jnp.int32)
+    temps = _sds(one_chip, (n_lanes,), jnp.float32)
+    tables = _sds(one_chip, (n_lanes, MB), jnp.int32)
     samp = (temps, lanes, temps, lanes)
     rows = _sds(one_chip, (4,), jnp.int32)
     ids = _sds(one_chip, (8,), jnp.int32)
@@ -239,7 +256,7 @@ def pool_programs(one_chip, no_persistent_cache):
             P, S, _sds(one_chip, (4, 256), jnp.int32), rows, rows,
             _sds(one_chip, (4, MB), jnp.int32), rows, False), 1, 1),
         "verify": (lambda: runner._verify_fn.lower(
-            P, S, _sds(one_chip, (LANES, 4), jnp.int32), lanes, lanes,
+            P, S, _sds(one_chip, (n_lanes, 4), jnp.int32), lanes, lanes,
             tables, *samp, False, False), 1, 4),
         "copy_block": (lambda: runner._copy_fn.lower(S, one, one), 0, None),
         "upload": (lambda: runner._upload_fn.lower(S, ids, payload), 0,
@@ -254,6 +271,20 @@ def test_runner_programs_keep_the_pool_in_place(pool_programs, program):
     an array of the pool's size (the layer loop carries the pool and
     scatters its new rows in place), and the program takes and returns
     the pool in one row-major format, so no call relayouts it."""
+    _check_pool_in_place(pool_programs, program)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "verify",
+                                     "copy_block"])
+def test_mla_runner_programs_keep_the_latent_pool_in_place(
+        mla_pool_programs, program):
+    """A 576-value latent row is stored padded to 640 lanes, so the
+    latent pool keeps the row-major format, and no program copies,
+    slices or relayouts it."""
+    _check_pool_in_place(mla_pool_programs, program)
+
+
+def _check_pool_in_place(pool_programs, program):
     state, programs = pool_programs
     lower, arg, out = programs[program]
     compiled = lower().compile()
@@ -264,4 +295,5 @@ def test_runner_programs_keep_the_pool_in_place(pool_programs, program):
     fmt_out = _pool_formats(outs if out is None else outs[out], state)
     assert fmt_in and fmt_in == fmt_out
     for f in fmt_in:
-        assert f.layout.major_to_minor == (0, 1, 2)
+        assert f.layout.major_to_minor == tuple(range(
+            len(f.layout.major_to_minor)))
